@@ -15,8 +15,7 @@ shapes at d_model=512:
    appear (the one-hot/cumsum bookkeeping is gone, not just faster).
 3. grouped expert FFN: einsum vs the Pallas kernel pair. On CPU the kernels
    execute in interpret mode (Python per grid cell), so this row is a
-   correctness/robustness exercise there; set REPRO_PALLAS_INTERPRET=0 on
-   TPU for a real comparison.
+   correctness/robustness exercise there; on a TPU they lower to Mosaic.
 
 Emits ``name,us_per_call,derived`` CSV lines (repo contract) and writes
 BENCH_moe_dispatch.json with tokens/s and dispatch-µs per shape.
@@ -103,6 +102,7 @@ def run(smoke: bool = False, out_path: str = "BENCH_moe_dispatch.json"):
     import numpy as np
 
     from repro.kernels import ops, ref
+    from repro.kernels.platform import interpret_default
 
     token_counts = [2048] if smoke else [8192, 32768]
     iters = 2 if smoke else 5
@@ -177,7 +177,7 @@ def run(smoke: bool = False, out_path: str = "BENCH_moe_dispatch.json"):
             "ffn_us_einsum": round(t_e * 1e6, 1),
             "ffn_us_pallas": round(t_p * 1e6, 1),
             "ffn_flops": flops,
-            "pallas_interpret": ops._interpret_default(),
+            "pallas_interpret": interpret_default(),
         }
         results["shapes"].append(rec)
         rows.append({

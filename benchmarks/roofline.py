@@ -23,7 +23,10 @@ from typing import Dict, List, Optional
 
 from repro import configs
 from repro.data.synthetic import INPUT_SHAPES
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import peaks_for
+
+# repro.launch.dryrun compiles for v5e pod meshes
+TARGET = peaks_for("TPU v5 lite")
 
 
 def count_params(cfg, active_only: bool = False) -> float:
@@ -84,9 +87,9 @@ def model_flops_per_device(arch: str, shape_name: str, n_chips: int) -> float:
 def roofline_row(rec: Dict) -> Optional[Dict]:
     if rec.get("status") != "ok":
         return None
-    t_compute = rec["flops"] / PEAK_FLOPS_BF16
-    t_memory = rec["traffic_bytes"] / HBM_BW
-    t_coll = rec["collective_bytes"].get("total", 0.0) / ICI_BW
+    t_compute = rec["flops"] / TARGET.bf16_flops
+    t_memory = rec["traffic_bytes"] / TARGET.hbm_bw
+    t_coll = rec["collective_bytes"].get("total", 0.0) / TARGET.ici_bw
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     mf = model_flops_per_device(rec["arch"], rec["shape"], rec["n_chips"])

@@ -85,7 +85,6 @@ def _sync_sweep_mesh_body(smoke: bool) -> Dict:
         bisect_rounds,
     )
     from repro.kernels import ops as kernel_ops
-    from repro.models.moe import _shard_map
 
     n_local = 256 if smoke else 1024
     m, k = 64, 8
@@ -102,7 +101,7 @@ def _sync_sweep_mesh_body(smoke: bool) -> Dict:
     q0 = jnp.zeros((m,), jnp.float32)
 
     def shard(fn):
-        return jax.jit(_shard_map(
+        return jax.jit(jax.shard_map(
             fn, mesh=mesh, in_specs=(P("data", None), P(None)), out_specs=P(None)
         ))
 
@@ -200,7 +199,7 @@ def _sync_sweep_mesh_body(smoke: bool) -> Dict:
                     new["q"] = jax.lax.pmean(new["q"], ("data",))
                 return out.combine_weights, new
 
-            sfn = jax.jit(_shard_map(
+            sfn = jax.jit(jax.shard_map(
                 block, mesh=mesh,
                 in_specs=(P("data", None), specs),
                 out_specs=(P("data", None), specs),
@@ -240,6 +239,8 @@ def _sync_sweep_mesh_body(smoke: bool) -> Dict:
 def run_sync_sweep(smoke: bool = False, out_path: str = "BENCH_router_sync.json") -> List[Dict]:
     """Spawn the forced-8-device child, collect its JSON, write the artifact."""
     env = dict(os.environ)
+    # a CPU forced-device tool: the child must never take an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env.setdefault("PYTHONPATH", "src")
     args = [sys.executable, "-m", "benchmarks.router_overhead", "--sync-child"]

@@ -47,7 +47,7 @@ def _kernel_microbench():
     rows.append({
         "name": f"kernel_bip_admm_T4_n{n}_m{m}",
         "us_per_call": round(us, 1),
-        "derived": "interpret-mode CPU; TPU est ~0.5ms/iter at n=32k m=128",
+        "derived": "interpret-mode CPU",
     })
 
     ee, c, d, f = 4, 128, 128, 256

@@ -20,7 +20,6 @@ from repro.core.ref_bip import (
     bisect_rounds,
     bip_dual_update,
     bip_dual_update_global,
-    bip_dual_update_masked,
     bip_dual_update_threshold,
     bip_route_reference,
     bip_topk,
@@ -44,7 +43,6 @@ __all__ = [
     "bisect_rounds",
     "bip_dual_update",
     "bip_dual_update_global",
-    "bip_dual_update_masked",
     "bip_dual_update_threshold",
     "bip_route_reference",
     "bip_topk",

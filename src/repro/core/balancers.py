@@ -326,16 +326,20 @@ class BIPBalancer(Balancer):
         # are present (they are guarded whenever carried, cfg.forecast or not)
         return ("q",) + tuple(k for k in ("q_ema", "q_err") if k in state)
 
-    def _solve(self, s, q0, cfg):
-        """Dispatch the ADMM dual update to the reference or Pallas kernel."""
+    def _solve(self, s, q0, cfg, token_mask=None):
+        """Single-program dual update: the Pallas histogram kernel under
+        use_kernel, else the exact sort form. Both take the serving token
+        mask natively, so a masked call runs the same solver as an
+        unmasked one."""
         if cfg.use_kernel:
             from repro.kernels import ops as kernel_ops  # lazy: import cycle
 
             return kernel_ops.bip_dual_update(
-                s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters
+                s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters,
+                token_mask=token_mask,
             )
         q, _ = ref_bip.bip_dual_update(
-            s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters
+            s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters, token_mask=token_mask
         )
         return q
 
@@ -347,7 +351,7 @@ class BIPBalancer(Balancer):
         # telemetry: dual-health scalars route() folds into the metrics —
         # strictly values the solve already produced (no extra collectives)
         tel: State = {}
-        if cfg.sync == "global" and cfg.use_kernel and token_mask is None:
+        if cfg.sync == "global" and cfg.use_kernel:
             # collective Pallas path: the kernel's (m, n_bins) histogram
             # counts are psum'd across the data axes between the count pass
             # and the rank location (kernels/ops.py). Empty axis_names
@@ -357,29 +361,23 @@ class BIPBalancer(Balancer):
             q = kernel_ops.bip_dual_update(
                 lax.stop_gradient(s), q0,
                 top_k=cfg.top_k, n_iters=cfg.bip_iters,
-                axis_names=axis_names,
+                axis_names=axis_names, token_mask=token_mask,
             )
             corrected = s - q[None, :]
             updates["q"] = q
-        elif cfg.sync == "global" or token_mask is not None:
-            # one implementation serves the mesh path (axis_names), the
-            # serving path (token_mask), AND the unsharded sync='global'
-            # reference (axes=()): all three share the bisection numerics,
-            # so a sharded global-sync run reproduces the single-device
-            # trajectory bit-for-bit at the dual level — the sort-based
-            # update would instead park q exactly ON the capacity-marginal
-            # token's score and make the comparison tie-degenerate.
-            if cfg.use_kernel:  # only reachable with a token mask
-                _warn_once(
-                    "kernel-masked",
-                    "use_kernel=True has no masked (serving-padding) form; "
-                    "falling back to the reference masked dual update.",
-                )
+        elif cfg.sync == "global":
+            # one implementation serves the mesh path (axis_names) AND the
+            # unsharded sync='global' reference (axes=()): both share the
+            # bisection numerics, so a sharded global-sync run reproduces
+            # the single-device trajectory bit-for-bit at the dual level —
+            # the sort-based update would instead park q exactly ON the
+            # capacity-marginal token's score and make the comparison
+            # tie-degenerate.
             # load forecaster: predict the pre-clamp order statistic t from
             # its EMA, bracket it by the EMA'd error, and let the bisection
             # validate the bracket in-band (free when stale, rounds saved
             # when right)
-            use_forecast = cfg.forecast and not cfg.use_kernel and "q_ema" in state
+            use_forecast = cfg.forecast and "q_ema" in state
             window = None
             if use_forecast:
                 half = cfg.forecast_margin * state["q_err"] + cfg.forecast_floor
@@ -410,13 +408,16 @@ class BIPBalancer(Balancer):
             updates["q"] = q
         elif local_shards > 1 and cfg.sync == "local":
             s_grp = lax.stop_gradient(s).reshape(local_shards, n // local_shards, m)
-            q_grp = jax.vmap(lambda sg: self._solve(sg, q0, cfg))(s_grp)  # (S, m)
+            m_grp = None if token_mask is None else token_mask.reshape(local_shards, -1)
+            q_grp = jax.vmap(lambda sg, mg: self._solve(sg, q0, cfg, mg))(
+                s_grp, m_grp
+            )  # (S, m)
             corrected = (
                 s.reshape(local_shards, -1, m) - q_grp[:, None, :]
             ).reshape(n, m)
             updates["q"] = q_grp.mean(axis=0)  # replicated warm start
         else:
-            q = self._solve(lax.stop_gradient(s), q0, cfg)
+            q = self._solve(lax.stop_gradient(s), q0, cfg, token_mask)
             corrected = s - q[None, :]
             updates["q"] = q
         if not cfg.bip_warm_start:

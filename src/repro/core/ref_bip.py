@@ -53,14 +53,39 @@ def bip_dual_update(
     *,
     top_k: int,
     n_iters: int,
+    token_mask: Optional[jnp.ndarray] = None,  # (n,) bool; False rows invisible
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """T iterations of the ADMM dual update. Returns (q, p).
 
     s:  (n, m) routing scores for the current batch (float).
     q0: (m,) warm-start expert prices (zeros on the first batch).
+
+    `token_mask` marks real rows (serving padding is False). Masked rows sink
+    to -1e30 out of every order statistic and the capacity index is
+    floor(n_real·k/m) over the real rows; a call with no real row leaves q0
+    unchanged. With an all-True mask the result is bitwise the unmasked one:
+    both take the same element of the same column as the order statistic.
     """
     n, m = s.shape
-    cap_idx = expert_kth_index(n, top_k, m)
+    if token_mask is None:
+        s_m = s
+        cap_idx = expert_kth_index(n, top_k, m)
+    else:
+        s_m = jnp.where(token_mask[:, None], s, jnp.asarray(-1e30, s.dtype))
+        n_real = jnp.sum(token_mask.astype(jnp.int32))
+        cap_idx = (n_real * top_k) // m  # traced counterpart of expert_kth_index
+
+    def q_step(q, p):
+        x = s_m - p[:, None]
+        if token_mask is None:
+            if cap_idx < 0:
+                return jnp.zeros_like(q)
+            return jnp.maximum(0.0, kth_largest(x, cap_idx, axis=0))
+        # traced rank: take it from the descending column sort (masked rows
+        # sort last); slack capacity (rank past the real rows) -> price 0
+        xs = -jnp.sort(-x, axis=0)
+        t = jnp.take(xs, jnp.minimum(cap_idx, n - 1), axis=0)
+        return jnp.where(cap_idx >= n_real, 0.0, jnp.maximum(0.0, t))
 
     def body(_, pq):
         q, _p = pq
@@ -69,13 +94,9 @@ def bip_dual_update(
         if top_k >= m:
             p = jnp.zeros((n,), s.dtype)
         else:
-            p = jnp.maximum(0.0, kth_largest(s - q[None, :], top_k, axis=-1))
+            p = jnp.maximum(0.0, kth_largest(s_m - q[None, :], top_k, axis=-1))
         # q_j = max(0, (nk/m + 1)-th largest of s_:j - p)
-        if cap_idx < 0:
-            q_new = jnp.zeros_like(q)
-        else:
-            q_new = jnp.maximum(0.0, kth_largest(s - p[:, None], cap_idx, axis=0))
-        return (q_new, p)
+        return (q_step(q, p), p)
 
     # inherit s's varying-manual-axes type (shard_map vma): inside a
     # shard_map over data axes the loop carry must be typed 'varying' from
@@ -83,6 +104,8 @@ def bip_dual_update(
     p0 = 0.0 * s[:, 0]
     q_init = q0.astype(s.dtype) + 0.0 * s[0]
     q, p = lax.fori_loop(0, n_iters, body, (q_init, p0))
+    if token_mask is not None:
+        q = jnp.where(n_real > 0, q, q_init)  # idle engine step: q stays
     return q, p
 
 
@@ -443,30 +466,6 @@ def bip_dual_update_global(
     if with_stats:
         return q, p, t
     return q, p
-
-
-def bip_dual_update_masked(
-    s: jnp.ndarray,
-    q0: jnp.ndarray,
-    mask: jnp.ndarray,  # (n,) bool; False rows are invisible to the update
-    *,
-    top_k: int,
-    n_iters: int,
-    n_bisect: int = 26,
-    fanout: int = 1,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """ADMM dual update over the REAL rows only (serving-chunk padding).
-
-    Single-device specialization of `bip_dual_update_global`: serving
-    chunks carry padding rows for static shapes (DESIGN.md §Serving); at
-    steady-state decode they can outnumber real tokens many-to-one, so
-    letting them into the dual update would drift q toward balancing
-    uniform filler instead of real traffic.
-    """
-    return bip_dual_update_global(
-        s, q0, top_k=top_k, n_iters=n_iters,
-        token_mask=mask, axis_names=(), n_bisect=n_bisect, fanout=fanout,
-    )
 
 
 def sanitize_duals(q: jnp.ndarray, abs_limit: float) -> Tuple[jnp.ndarray, jnp.ndarray]:

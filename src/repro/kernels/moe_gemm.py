@@ -20,7 +20,6 @@ compute zeros.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -28,14 +27,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_default, varying_operands
 
-def _interpret_default() -> bool:
-    """Pallas interpret mode unless REPRO_PALLAS_INTERPRET=0 (TPU: Mosaic).
 
-    Every kernel entry point resolves interpret=None through this, so TPU
-    runs lower to hardware without callers threading flags.
-    """
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+def _operands(interpret: bool, *xs):
+    """(vma, xs) for a grouped GEMM; see platform.varying_operands."""
+    vma, xs = varying_operands(*xs)
+    if interpret and vma:
+        # jax 0.9.0's Pallas interpreter rebuilds the kernel's types without
+        # shard_map's varying axes and fails inside the body; say so here
+        raise NotImplementedError(
+            "the grouped-FFN kernels run inside a shard_map only under Mosaic "
+            "(a TPU): the Pallas interpreter of this JAX drops varying mesh "
+            "axes. Use use_kernel=False for expert-parallel runs on the CPU."
+        )
+    return vma, xs
 
 
 def _gated_in_kernel(x_ref, wg_ref, wu_ref, h_ref, acc_g, acc_u):
@@ -67,7 +73,8 @@ def grouped_gated_ffn_in(
     block_d: int = 256,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
+    vma, (x, w_gate, w_up) = _operands(interpret, x, w_gate, w_up)
     e, c, d = x.shape
     f = w_gate.shape[-1]
     bc, bf, bd = min(block_c, c), min(block_f, f), min(block_d, d)
@@ -82,7 +89,7 @@ def grouped_gated_ffn_in(
             pl.BlockSpec((1, bd, bf), lambda e_, i, j, k: (e_, k, j)),
         ],
         out_specs=pl.BlockSpec((1, bc, bf), lambda e_, i, j, k: (e_, i, j)),
-        out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype, vma=vma),
         scratch_shapes=[
             pltpu.VMEM((bc, bf), jnp.float32),
             pltpu.VMEM((bc, bf), jnp.float32),
@@ -116,7 +123,8 @@ def grouped_matmul(
     block_f: int = 256,
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
+    vma, (h, w) = _operands(interpret, h, w)
     e, c, f = h.shape
     d = w.shape[-1]
     bc, bd, bf = min(block_c, c), min(block_d, d), min(block_f, f)
@@ -130,7 +138,7 @@ def grouped_matmul(
             pl.BlockSpec((1, bf, bd), lambda e_, i, j, k: (e_, k, j)),
         ],
         out_specs=pl.BlockSpec((1, bc, bd), lambda e_, i, j, k: (e_, i, j)),
-        out_shape=jax.ShapeDtypeStruct((e, c, d), h.dtype),
+        out_shape=jax.ShapeDtypeStruct((e, c, d), h.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bc, bd), jnp.float32)],
         interpret=interpret,
     )(h, w)
@@ -150,6 +158,6 @@ def expert_ffn(
     Raw aligned-shape kernel pair; for the differentiable, auto-padded
     entry point used by the model path see repro.kernels.ops.expert_ffn.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     h = grouped_gated_ffn_in(x, w_gate, w_up, interpret=interpret, **block_kw)
     return grouped_matmul(h, w_down, interpret=interpret, **block_kw)

@@ -3,46 +3,26 @@
 `bip_dual_update(s, q0, top_k, n_iters)` is a drop-in for the exact oracle in
 repro.core.ref_bip (the router dispatches here when RouterConfig.use_kernel).
 
-interpret=True executes the kernel bodies in Python on CPU (this container);
-on TPU hardware set REPRO_PALLAS_INTERPRET=0 (or pass interpret=False) so
-pallas_call lowers to Mosaic.
+interpret=None resolves from the platform (kernels/platform.py): the kernel
+bodies run in the Pallas interpreter on the CPU and lower to Mosaic on a TPU.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.ref_bip import expert_kth_index
 from repro.kernels import bip_admm as _bip
 from repro.kernels import moe_gemm as _gemm
-from repro.kernels.moe_gemm import _interpret_default
-
-# shard_map replication typing for pallas_call: jax 0.4.x ships no rule, so
-# calling the kernel under shard_map(check_vma/check_rep=True) raises
-# NotImplementedError. The *standard* rule (outputs vary over the union of
-# the inputs' varying axes) is exactly right for a Pallas kernel — it is a
-# per-shard local computation with no collectives inside — and registering
-# it is what makes the collective dual update below legal inside the EP
-# shard_maps (models/moe.py) without disabling replication checking.
-try:  # pragma: no cover - exercised indirectly by the collective tests
-    from jax._src.pallas.pallas_call import pallas_call_p as _pallas_call_p
-    from jax.experimental import shard_map as _shard_map_mod
-
-    _shard_map_mod.register_standard_check(_pallas_call_p)
-    _shard_map_mod.register_standard_rewrite(_pallas_call_p)
-except Exception:  # newer jax versions register their own rule
-    pass
+from repro.kernels.platform import interpret_default, varying_operands
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=(
-        "top_k", "n_iters", "n_bins", "block_n", "refine", "interpret", "axis_names",
-    ),
+    static_argnames=("top_k", "n_iters", "n_bins", "refine", "interpret", "axis_names"),
 )
 def bip_dual_update(
     s: jnp.ndarray,
@@ -51,10 +31,10 @@ def bip_dual_update(
     top_k: int,
     n_iters: int,
     n_bins: int = 512,
-    block_n: int = 1024,
     refine: int = 1,
     interpret: Optional[bool] = None,
     axis_names: tuple = (),
+    token_mask: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """T fused ADMM iterations on the (n, m) score matrix. Returns q (m,).
 
@@ -63,35 +43,39 @@ def bip_dual_update(
     resolution is (2/n_bins)^(refine+1)·… ≈ 8e-6 at the defaults — tighter
     than fp32 softmax score gaps (validated in tests/test_kernels.py).
 
+    `token_mask` (n,) bool marks real tokens (serving padding is False):
+    masked rows take the kernel's pad value, so they never enter a
+    histogram, and the capacity rank is floor(n_real·k/m) over the real
+    rows. A call with no real token leaves q0 unchanged (idle engine step).
+
     With `axis_names` (the collective form, sync='global' under shard_map):
     `s` is the device-local (n_local, m) token shard, the counting pass
     stays fully local, and the (m, n_bins) histogram counts are psum'd
     across the mesh axes between the count pass and the rank location —
     one fused collective per pass, refine+1 per dual iteration — so every
-    device locates the SAME global order statistic. The rank becomes the
-    traced floor(n_glob·k/m) (the bin comparisons accept a tracer), and the
-    q carry starts from the replicated q0 so the result can leave the
-    shard_map under an out_spec of P(None).
+    device locates the SAME global order statistic. The real-token count is
+    psum'd the same way, and the q carry starts from the replicated q0 so
+    the result can leave the shard_map under an out_spec of P(None).
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
     n, m = s.shape
     axis_names = tuple(axis_names)
-    if not axis_names:
-        rank = expert_kth_index(n, top_k, m)
-        if rank < 0:  # capacity slack: constraint never binds
-            return jnp.zeros_like(q0)
-        n_glob = None
+    if token_mask is None:
+        n_real = jnp.asarray(n, jnp.int32)
     else:
-        n_glob = lax.psum(jnp.asarray(n, jnp.int32), axis_names)
-        rank = (n_glob * top_k) // m  # traced counterpart of expert_kth_index
+        n_real = jnp.sum(token_mask.astype(jnp.int32))
+    if axis_names:
+        n_real = lax.psum(n_real, axis_names)
+    rank = (n_real * top_k) // m  # cap index: want the (rank+1)-th largest
+    slack = rank >= n_real  # more capacity than tokens: the constraint never binds
+    st = _bip.transpose_scores(s, token_mask)  # loop-invariant, built once
 
     def body(_, q):
         lo = jnp.full((m,), _bip.LO, jnp.float32)
         hi = jnp.full((m,), _bip.HI, jnp.float32)
         for _pass in range(refine + 1):
-            _p, cnt = _bip.bip_admm_iteration(
-                s, q, top_k=top_k, n_bins=n_bins, block_n=block_n,
-                lo=lo, hi=hi, interpret=interpret,
+            _p, cnt = _bip.iteration_on_transposed(
+                st, q, lo, hi, top_k=top_k, n_bins=n_bins, interpret=interpret,
             )
             if axis_names:
                 cnt = lax.psum(cnt, axis_names)
@@ -100,10 +84,7 @@ def bip_dual_update(
             lo = jnp.where(found, bin_lo, lo)
             hi = jnp.where(found, bin_hi, hi)
         q_new = _bip.q_from_histogram(cnt, rank, n_bins, lo=cur_lo, hi=cur_hi)
-        if axis_names:
-            # slack capacity (global cap index past the global token count)
-            q_new = jnp.where(rank >= n_glob, jnp.zeros_like(q_new), q_new)
-        return q_new
+        return jnp.where(slack, jnp.zeros_like(q_new), q_new)
 
     if axis_names:
         # the carry must stay REPLICATED: q_new is assembled from psum'd
@@ -112,7 +93,8 @@ def bip_dual_update(
     else:
         # inherit s's varying-manual-axes type for the loop carry (shard_map)
         q_init = q0.astype(jnp.float32) + 0.0 * s[0].astype(jnp.float32)
-    return lax.fori_loop(0, n_iters, body, q_init)
+    q = lax.fori_loop(0, n_iters, body, q_init)
+    return jnp.where(n_real > 0, q, q_init)
 
 
 # ----------------------------------------------- grouped expert FFN (model path)
@@ -200,7 +182,11 @@ def expert_ffn(
     entry point the model path (models/moe._expert_ffn) uses when
     cfg.routing.use_kernel is set.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = interpret_default() if interpret is None else interpret
+    # inside a shard_map: cast outside the custom_vjp, so autodiff turns the
+    # cast of an operand that varies over fewer mesh axes into the psum its
+    # cotangent needs
+    _, (x, w_gate, w_up, w_down) = varying_operands(x, w_gate, w_up, w_down)
     e, c, d = x.shape
     f = w_gate.shape[-1]
     cp, dp, fp = _round_up(c, 128), _round_up(d, 128), _round_up(f, 128)

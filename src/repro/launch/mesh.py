@@ -6,7 +6,10 @@ then calls make_production_mesh().
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,7 +20,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model places activations with with_sharding_constraint,
+    # which refuses the Explicit axes jax.make_mesh defaults to
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -29,8 +34,33 @@ def make_host_mesh(data: int = 1, model: int = 1):
     return Mesh(devs, ("data", "model"))
 
 
-# TPU v5e hardware constants used by the roofline analysis (benchmarks/).
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link (~per chip per direction)
-HBM_BYTES = 16 * 1024**3      # 16 GiB per chip
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    bf16_flops: float  # FLOP/s
+    hbm_bw: float      # bytes/s
+    ici_bw: float      # bytes/s per link, per direction
+    source: str
+
+
+# keyed by jax.Device.device_kind
+PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops=197e12,
+        hbm_bw=819e9,
+        ici_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e"',
+    ),
+}
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """Peaks of `device_kind`; a kind not in the table is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None

@@ -54,9 +54,11 @@ def main(argv=None):
     import jax
 
     from repro import configs
+    from repro.launch.compile_cache import setup_compile_cache
     from repro.models import build_model
     from repro.serving import ContinuousBatchingEngine
 
+    setup_compile_cache()
     cfg = configs.reduced_for_smoke(args.arch) if args.reduced else configs.get(args.arch)
     model = build_model(cfg)
     if args.ckpt:

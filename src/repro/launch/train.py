@@ -217,6 +217,9 @@ def main(argv=None):
     if args.resume and not args.ckpt_dir:
         ap.error("--resume requires --ckpt-dir")
 
+    from repro.launch.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
     if args.production and args.coordinator:
         import jax
 

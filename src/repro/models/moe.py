@@ -41,18 +41,6 @@ from repro.telemetry.trace import named_span
 Params = Dict[str, jnp.ndarray]
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """jax.shard_map when available, else the jax.experimental spelling
-    (pre-0.5 jax exposes it only there, with check_vma named check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma)
-
-
 def router_config(cfg: ModelConfig, data_axes: Tuple[str, ...] = ()) -> RouterConfig:
     """RouterConfig for this model — one conversion point (RoutingSpec shim)."""
     return cfg.routing.to_router_config(data_axes=data_axes)
@@ -395,7 +383,7 @@ def moe_ffn_ep2d(
     if token_mask is not None:
         in_specs.append(P(data_axes if token_sharded else None))
         args.append(token_mask)
-    fn = _shard_map(
+    fn = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -546,7 +534,7 @@ def moe_ffn_ep2ds(
     if token_mask is not None:
         in_specs.append(P(data_axes))
         args.append(token_mask)
-    fn = _shard_map(
+    fn = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=tuple(in_specs),
@@ -660,7 +648,7 @@ def moe_ffn_ep(
     if token_mask is not None:
         in_specs.append(P(data_axes if data_axes else None))
         args.append(token_mask)
-    f = _shard_map(
+    f = jax.shard_map(
         block,
         mesh=mesh,
         in_specs=tuple(in_specs),

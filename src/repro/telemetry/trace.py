@@ -16,7 +16,6 @@ stops after M, so a capture costs nothing outside the window.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 from typing import Optional, Tuple
 
@@ -30,10 +29,7 @@ def named_span(name: str):
 
 def trace_span(name: str):
     """Host-side profiler span for un-traced Python phases."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # profiler backend unavailable (e.g. stripped builds)
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 def profile_window(spec: Optional[str]) -> Optional[Tuple[int, int]]:

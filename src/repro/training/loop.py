@@ -521,24 +521,38 @@ def train_loop(
         if latest_step(ckpt_dir) is not None:
             start_step, state = manager.restore_train_state()
             data_state = manager.restore_data_state(start_step)
-    if state is None:
-        state = init_train_state(model, key, opt_cfg)
-
     loop_start = 0  # index the enumerate starts at
     if is_stream and data_state is not None:
         batches.load_state_dict(data_state)  # O(1) seek past the consumed prefix
         loop_start = start_step
 
     st_specs = b_specs = None
-    if mesh is not None:
+    if mesh is None:
+        if state is None:
+            state = init_train_state(model, key, opt_cfg)
+    else:
+        from jax.sharding import NamedSharding
+
         from repro.distributed.sharding import (
             batch_specs,
             shard_tree,
             train_state_specs,
         )
 
-        st_specs = train_state_specs(state, model.cfg, mesh)
-        state = shard_tree(state, st_specs, mesh)
+        init = lambda k: init_train_state(model, k, opt_cfg)
+        st_specs = train_state_specs(
+            jax.eval_shape(init, key) if state is None else state, model.cfg, mesh
+        )
+        if state is None:
+            # every leaf is created on its own shards: the whole state of a
+            # model that needs the mesh does not fit one device
+            shardings = jax.tree.map(
+                lambda s: NamedSharding(mesh, s), st_specs,
+                is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec),
+            )
+            state = jax.jit(init, out_shardings=shardings)(key)
+        else:
+            state = shard_tree(state, st_specs, mesh)
 
     guarded = guard is not None or (faults is not None and faults.get("nan_grad"))
     tguard = None

@@ -7,7 +7,9 @@ masked serving path, the sync='global' threshold branch, the forecaster
 EMA updates, and the dual-health watchdog. tests/test_balancers.py runs
 this next to the registry-backed route() and asserts bitwise-identical
 RouterOutput fields and state trajectories. Do not "fix" or modernize this
-file — its value is being the old code.
+file — its value is being the old code. The one deliberate change since the
+snapshot: BIP's masked (serving) path runs the config's own dual solver with
+the mask, as route() does, instead of a masked bisection fallback.
 """
 from __future__ import annotations
 
@@ -57,14 +59,17 @@ def _aux_loss(
     return cfg.aux_loss_alpha * jnp.sum(f * p_mean)
 
 
-def _bip_q(s: jnp.ndarray, q0: jnp.ndarray, cfg: RouterConfig) -> jnp.ndarray:
+def _bip_q(s: jnp.ndarray, q0: jnp.ndarray, cfg: RouterConfig,
+           token_mask=None) -> jnp.ndarray:
     if cfg.use_kernel:
         from repro.kernels import ops as kernel_ops
 
         return kernel_ops.bip_dual_update(
-            s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters
+            s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters, token_mask=token_mask
         )
-    q, _ = ref_bip.bip_dual_update(s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters)
+    q, _ = ref_bip.bip_dual_update(
+        s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters, token_mask=token_mask
+    )
     return q
 
 
@@ -100,18 +105,18 @@ def legacy_route(
     global_axes = tuple(cfg.data_axes) if cfg.sync == "global" else ()
 
     if cfg.strategy == "bip":
-        if cfg.sync == "global" and cfg.use_kernel and token_mask is None:
+        if cfg.sync == "global" and cfg.use_kernel:
             from repro.kernels import ops as kernel_ops
 
             q = kernel_ops.bip_dual_update(
                 lax.stop_gradient(s), q0,
                 top_k=cfg.top_k, n_iters=cfg.bip_iters,
-                axis_names=global_axes,
+                axis_names=global_axes, token_mask=token_mask,
             )
             corrected = s - q[None, :]
             new_q = q
-        elif cfg.sync == "global" or token_mask is not None:
-            use_forecast = cfg.forecast and not cfg.use_kernel and "q_ema" in state
+        elif cfg.sync == "global":
+            use_forecast = cfg.forecast and "q_ema" in state
             window = None
             if use_forecast:
                 half = cfg.forecast_margin * state["q_err"] + cfg.forecast_floor
@@ -132,13 +137,16 @@ def legacy_route(
             new_q = q
         elif local_shards > 1 and cfg.sync == "local":
             s_grp = lax.stop_gradient(s).reshape(local_shards, n // local_shards, m)
-            q_grp = jax.vmap(lambda sg: _bip_q(sg, q0, cfg))(s_grp)  # (S, m)
+            m_grp = None if token_mask is None else token_mask.reshape(local_shards, -1)
+            q_grp = jax.vmap(lambda sg, mg: _bip_q(sg, q0, cfg, mg))(
+                s_grp, m_grp
+            )  # (S, m)
             corrected = (
                 s.reshape(local_shards, -1, m) - q_grp[:, None, :]
             ).reshape(n, m)
             new_q = q_grp.mean(axis=0)
         else:
-            q = _bip_q(lax.stop_gradient(s), q0, cfg)
+            q = _bip_q(lax.stop_gradient(s), q0, cfg, token_mask)
             corrected = s - q[None, :]
             new_q = q
         w, idx = _topk_select(s, corrected, cfg)

@@ -141,7 +141,6 @@ def test_registry_parity_global_mesh_4x2():
         + r"""
 sys.path.insert(0, "tests")
 from repro.core import RouterConfig, init_router_state, route
-from repro.models.moe import _shard_map
 from _legacy_router import legacy_route
 
 n, m, k = 64, 16, 4
@@ -167,7 +166,7 @@ for strategy in ("topk", "aux_loss", "lossfree", "bip", "bip_forecast"):
 
     st = init_router_state(cfg)
     state_spec = jax.tree.map(lambda _: P(), st)
-    fn = jax.jit(_shard_map(
+    fn = jax.jit(jax.shard_map(
         pair, mesh=mesh,
         in_specs=(P("data", None), state_spec, state_spec),
         out_specs=((P("data", None), P("data", None), P(), state_spec),) * 2,

@@ -12,7 +12,6 @@ from repro.core import (
     balance_metrics,
     bip_dual_update,
     bip_dual_update_global,
-    bip_dual_update_masked,
     bip_dual_update_threshold,
     bip_route_reference,
     init_router_state,
@@ -168,7 +167,9 @@ def test_masked_dual_update_equals_dense_subset(seed, t, frac):
     mask[0] = True  # never all-padding
     jmask = jnp.asarray(mask)
 
-    q_m, _ = bip_dual_update_masked(s, q0, jmask, top_k=k, n_iters=t, n_bisect=40)
+    q_m, _ = bip_dual_update_global(
+        s, q0, top_k=k, n_iters=t, token_mask=jmask, n_bisect=40
+    )
     q_dense, _ = bip_dual_update(
         jnp.asarray(np.asarray(s)[mask]), q0, top_k=k, n_iters=t
     )
@@ -184,11 +185,40 @@ def test_masked_dual_update_equals_dense_subset(seed, t, frac):
     assert not mismatched, mismatched[:5]
 
     # all-True mask == the unmasked threshold variant (same bisection)
-    q_all, _ = bip_dual_update_masked(
-        s, q0, jnp.ones((n,), bool), top_k=k, n_iters=t, n_bisect=40
+    q_all, _ = bip_dual_update_global(
+        s, q0, top_k=k, n_iters=t, token_mask=jnp.ones((n,), bool), n_bisect=40
     )
     q_thr, _ = bip_dual_update_threshold(s, q0, top_k=k, n_iters=t, n_bisect=40)
     np.testing.assert_allclose(np.asarray(q_all), np.asarray(q_thr), atol=1e-6)
+
+
+@given(
+    seed=st.integers(0, 2**31 - 1),
+    t=st.sampled_from([2, 4]),
+    frac=st.floats(0.0, 0.9),
+)
+@settings(max_examples=25, deadline=None)
+def test_sort_dual_mask_is_exact(seed, t, frac):
+    """The sort form's masked update (the serving path of a sync='local'
+    gate) is exact, not approximate: bitwise the unmasked update over the
+    real rows alone, bitwise the unmasked update under an all-True mask,
+    and an all-padding call leaves the warm start unchanged."""
+    rng = np.random.default_rng(seed)
+    n, m, k = 96, 8, 2
+    s = _scores(rng, n, m, skew=1.0)
+    q0 = jnp.asarray(rng.uniform(0, 0.2, (m,)).astype(np.float32))
+    mask = rng.random(n) >= frac
+    mask[0] = True
+    q_m, _ = bip_dual_update(s, q0, top_k=k, n_iters=t, token_mask=jnp.asarray(mask))
+    q_real, _ = bip_dual_update(
+        jnp.asarray(np.asarray(s)[mask]), q0, top_k=k, n_iters=t
+    )
+    np.testing.assert_array_equal(np.asarray(q_m), np.asarray(q_real))
+    q_all, _ = bip_dual_update(s, q0, top_k=k, n_iters=t, token_mask=jnp.ones((n,), bool))
+    q_plain, _ = bip_dual_update(s, q0, top_k=k, n_iters=t)
+    np.testing.assert_array_equal(np.asarray(q_all), np.asarray(q_plain))
+    q_idle, _ = bip_dual_update(s, q0, top_k=k, n_iters=t, token_mask=jnp.zeros((n,), bool))
+    np.testing.assert_array_equal(np.asarray(q_idle), np.asarray(q0))
 
 
 # ------------------------------------------- fused multi-threshold bisection
@@ -285,8 +315,9 @@ def test_masked_dual_update_fanout_matches_dense_subset(seed, fanout, frac):
     q0 = jnp.asarray(rng.uniform(0, 0.2, (m,)).astype(np.float32))
     mask = rng.random(n) < frac
     mask[0] = True
-    q_m, _ = bip_dual_update_masked(
-        s, q0, jnp.asarray(mask), top_k=k, n_iters=2, n_bisect=26, fanout=fanout
+    q_m, _ = bip_dual_update_global(
+        s, q0, top_k=k, n_iters=2, token_mask=jnp.asarray(mask), n_bisect=26,
+        fanout=fanout,
     )
     q_dense, _ = bip_dual_update(
         jnp.asarray(np.asarray(s)[mask]), q0, top_k=k, n_iters=2

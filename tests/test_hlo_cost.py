@@ -74,7 +74,7 @@ import jax, jax.numpy as jnp, numpy as np, sys
 sys.path.insert(0, "src")
 from repro.launch.hlo_cost import analyze_compiled
 from jax.sharding import NamedSharding, PartitionSpec as P
-mesh = jax.make_mesh((4,), ("m",))
+mesh = jax.make_mesh((4,), ("m",), axis_types=(jax.sharding.AxisType.Auto,))
 s = NamedSharding(mesh, P("m", None))
 a = jax.ShapeDtypeStruct((64, 64), jnp.float32, sharding=s)
 

@@ -25,7 +25,7 @@ def test_bip_iteration_p_matches_exact(n, m, k):
     """The kernel's row-price p must match the exact (k+1)-th largest."""
     s = _scores(0, n, m)
     q = jnp.asarray(np.random.default_rng(1).uniform(0, 0.3, (m,)), jnp.float32)
-    p_kern, cnt = bip_admm.bip_admm_iteration(s, q, top_k=k, block_n=128)
+    p_kern, cnt = bip_admm.bip_admm_iteration(s, q, top_k=k)
     p_ref = ref.bip_iteration_ref(s, q, top_k=k)
     np.testing.assert_allclose(np.asarray(p_kern), np.asarray(p_ref), atol=1e-6)
     # histogram counts match the oracle
@@ -37,7 +37,7 @@ def test_bip_iteration_p_matches_exact(n, m, k):
 def test_bip_iteration_dtype_sweep(dtype):
     s = _scores(2, 384, 16).astype(dtype)
     q = jnp.zeros((16,), jnp.float32)
-    p_kern, cnt = bip_admm.bip_admm_iteration(s, q, top_k=4, block_n=128)
+    p_kern, cnt = bip_admm.bip_admm_iteration(s, q, top_k=4)
     p_ref = ref.bip_iteration_ref(s.astype(jnp.float32), q, top_k=4)
     np.testing.assert_allclose(np.asarray(p_kern), np.asarray(p_ref), atol=5e-3)
 
@@ -57,7 +57,7 @@ def test_bip_dual_update_kernel_close_to_exact(seed, n, m, k, t):
     k = min(k, m)
     s = _scores(seed, n, m, skew=1.5)
     q0 = jnp.zeros((m,), jnp.float32)
-    q_kern = ops.bip_dual_update(s, q0, top_k=k, n_iters=t, block_n=256)
+    q_kern = ops.bip_dual_update(s, q0, top_k=k, n_iters=t)
     q_ref, _ = exact_dual(s, q0, top_k=k, n_iters=t)
     np.testing.assert_allclose(
         np.asarray(q_kern), np.asarray(q_ref), atol=2.0 / 512 + 5e-3
@@ -69,6 +69,24 @@ def test_bip_dual_update_kernel_close_to_exact(seed, n, m, k, t):
     # cold starts at tiny T can leave both unbalanced; the kernel must simply
     # track the oracle's balance, not beat it.
     assert vio_k <= 1.3 * vio_r + 0.3, (vio_k, vio_r)
+
+
+@pytest.mark.parametrize("n,m,k", [(512, 16, 4), (1000, 64, 8)])
+def test_bip_kernel_masked_matches_exact_masked(n, m, k):
+    """The kernel's serving form: masked rows are invisible (bitwise the
+    kernel on the real rows alone — the histogram counts are the same exact
+    integers), q tracks the exact masked dual within histogram resolution,
+    and an all-padding call leaves q unchanged."""
+    s = _scores(6, n, m, skew=1.5)
+    mask = np.random.default_rng(7).random(n) < 0.4
+    q0 = jnp.zeros((m,), jnp.float32)
+    q_k = ops.bip_dual_update(s, q0, top_k=k, n_iters=4, token_mask=jnp.asarray(mask))
+    q_alone = ops.bip_dual_update(s[np.flatnonzero(mask)], q0, top_k=k, n_iters=4)
+    np.testing.assert_array_equal(np.asarray(q_k), np.asarray(q_alone))
+    q_r, _ = exact_dual(s, q0, top_k=k, n_iters=4, token_mask=jnp.asarray(mask))
+    np.testing.assert_allclose(np.asarray(q_k), np.asarray(q_r), atol=2.0 / 512 + 5e-3)
+    q_idle = ops.bip_dual_update(s, q_r, top_k=k, n_iters=4, token_mask=jnp.zeros((n,), bool))
+    np.testing.assert_array_equal(np.asarray(q_idle), np.asarray(q_r))
 
 
 def test_bip_kernel_in_router_end_to_end():
@@ -121,7 +139,6 @@ def test_bip_kernel_collective_matches_reference_on_mesh():
     _run(PRELUDE + r"""
 from repro.core.ref_bip import bip_dual_update_global
 from repro.kernels import ops
-from repro.models.moe import _shard_map
 
 mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
 
@@ -136,8 +153,8 @@ for n, m, k, t in ((512, 16, 4, 4), (1024, 64, 8, 2)):
         return ops.bip_dual_update(s_loc, q, top_k=k, n_iters=t,
                                    axis_names=("data",))
 
-    fn = _shard_map(collective, mesh=mesh,
-                    in_specs=(P("data", None), P(None)), out_specs=P(None))
+    fn = jax.shard_map(collective, mesh=mesh,
+                       in_specs=(P("data", None), P(None)), out_specs=P(None))
     with mesh:
         q_mesh = np.asarray(jax.device_get(jax.jit(fn)(s, q0)))
 

@@ -272,7 +272,6 @@ def test_global_sync_dual_trajectory_matches_unsharded_route():
     _run(PRELUDE + r"""
 from jax import lax
 from repro.core import RouterConfig, init_router_state, route
-from repro.models.moe import _shard_map
 
 mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
 STEPS, N, LAYERS = 10, 512, 2
@@ -289,7 +288,7 @@ for m, k, iters in ((16, 4, 4), (64, 8, 14)):
         def block(lg_loc, q_in):
             out = route(lg_loc, {"q": q_in}, cfg)
             return out.state["q"], lax.psum(out.metrics["load"], "data")
-        return _shard_map(
+        return jax.shard_map(
             block, mesh=mesh,
             in_specs=(P("data", None), P(None)),
             out_specs=(P(None), P(None)),
@@ -414,7 +413,6 @@ def test_forecast_warm_start_sharded_matches_single_device():
     inside the psum'd count, so shard-local data never skews the bracket."""
     _run(PRELUDE + r"""
 from repro.core import RouterConfig, init_router_state, route
-from repro.models.moe import _shard_map
 
 mesh = Mesh(np.array(jax.devices()).reshape(4, 2), ("data", "model"))
 m, k, N, STEPS = 16, 4, 512, 8
@@ -431,7 +429,7 @@ specs = jax.tree.map(lambda _: P(None), state0)
 def sharded_step(logits, state):
     def block(lg_loc, st):
         return route(lg_loc, st, cfg_g).state
-    return _shard_map(block, mesh=mesh,
+    return jax.shard_map(block, mesh=mesh,
                       in_specs=(P("data", None), specs), out_specs=specs,
                       )(logits, state)
 
