@@ -154,15 +154,6 @@ def _bench_expert_choice(args) -> None:
     _rows(expert_choice_compare.main())
 
 
-def _bench_telemetry_overhead(args) -> None:
-    if args.skip_train:
-        return
-    print("# telemetry overhead (instrumented vs bare train step)", flush=True)
-    from benchmarks import telemetry_overhead
-
-    _rows(telemetry_overhead.run(smoke=not args.full))
-
-
 def _bench_serve_throughput(args) -> None:
     if args.skip_train:
         return
@@ -198,7 +189,6 @@ BENCHES = {
     "steptime_model": _bench_steptime_model,
     "capacity_ablation": _bench_capacity_ablation,
     "expert_choice": _bench_expert_choice,
-    "telemetry_overhead": _bench_telemetry_overhead,
     "serve_throughput": _bench_serve_throughput,
     "roofline": _bench_roofline,
 }

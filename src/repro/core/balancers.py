@@ -51,6 +51,7 @@ from jax import lax
 from repro.core import ref_bip
 from repro.core.metrics import balance_metrics
 from repro.core.types import RouterConfig
+from repro.telemetry.trace import named_span
 
 Array = jnp.ndarray
 State = Dict[str, Array]
@@ -334,10 +335,11 @@ class BIPBalancer(Balancer):
         if cfg.use_kernel:
             from repro.kernels import ops as kernel_ops  # lazy: import cycle
 
-            return kernel_ops.bip_dual_update(
-                s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters,
-                token_mask=token_mask,
-            )
+            with named_span("bip_kernel"):
+                return kernel_ops.bip_dual_update(
+                    s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters,
+                    token_mask=token_mask,
+                )
         q, _ = ref_bip.bip_dual_update(
             s, q0, top_k=cfg.top_k, n_iters=cfg.bip_iters, token_mask=token_mask
         )
@@ -358,11 +360,12 @@ class BIPBalancer(Balancer):
             # degrades to the plain single-device kernel.
             from repro.kernels import ops as kernel_ops  # lazy: import cycle
 
-            q = kernel_ops.bip_dual_update(
-                lax.stop_gradient(s), q0,
-                top_k=cfg.top_k, n_iters=cfg.bip_iters,
-                axis_names=axis_names, token_mask=token_mask,
-            )
+            with named_span("bip_kernel"):
+                q = kernel_ops.bip_dual_update(
+                    lax.stop_gradient(s), q0,
+                    top_k=cfg.top_k, n_iters=cfg.bip_iters,
+                    axis_names=axis_names, token_mask=token_mask,
+                )
             corrected = s - q[None, :]
             updates["q"] = q
         elif cfg.sync == "global":

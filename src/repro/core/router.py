@@ -39,6 +39,7 @@ from jax import lax
 
 from repro.core import balancers, ref_bip
 from repro.core.types import RouterConfig, RouterOutput, init_router_state
+from repro.telemetry.trace import named_span
 
 
 # ------------------------------------------------------- dispatch plan
@@ -193,29 +194,31 @@ def route(
             "evict a token when later tokens arrive), so the masked "
             "serving/decode path would break causality."
         )
-    s = compute_scores(logits, cfg)
+    with named_span("router/scores"):
+        s = compute_scores(logits, cfg)
     # carry every state key through unchanged unless a hook updates it, so
     # the router-state pytree structure is stable across scan/loop carries
     new_state = dict(state)
 
     if cfg.guard_duals:
-        # dual-health watchdog: the balancer's guarded keys (q, plus e.g.
-        # the bip forecaster EMAs) are one coupled carry, so any
-        # non-finite/runaway entry in any of them resets them all to safe
-        # init (zeros — the fresh-layer warm start). jnp.where on the
-        # scalar verdict keeps healthy carries bitwise unchanged, so the
-        # watchdog is free to leave enabled.
-        gkeys = bal.guard_keys(state)
-        vecs = [state[k] for k in gkeys]
-        stacked = jnp.concatenate(vecs) if len(vecs) > 1 else vecs[0]
-        _, dual_healthy = ref_bip.sanitize_duals(stacked, cfg.dual_abs_limit)
-        for k in gkeys:
-            new_state[k] = jnp.where(
-                dual_healthy, state[k], jnp.zeros_like(state[k])
-            )
-        # the hooks below must read the sanitized carry (a copy, so later
-        # new_state updates cannot leak into the hooks' view of `state`)
-        state = dict(new_state)
+        with named_span("router/score_adjust"):
+            # dual-health watchdog: the balancer's guarded keys (q, plus e.g.
+            # the bip forecaster EMAs) are one coupled carry, so any
+            # non-finite/runaway entry in any of them resets them all to safe
+            # init (zeros — the fresh-layer warm start). jnp.where on the
+            # scalar verdict keeps healthy carries bitwise unchanged, so the
+            # watchdog is free to leave enabled.
+            gkeys = bal.guard_keys(state)
+            vecs = [state[k] for k in gkeys]
+            stacked = jnp.concatenate(vecs) if len(vecs) > 1 else vecs[0]
+            _, dual_healthy = ref_bip.sanitize_duals(stacked, cfg.dual_abs_limit)
+            for k in gkeys:
+                new_state[k] = jnp.where(
+                    dual_healthy, state[k], jnp.zeros_like(state[k])
+                )
+            # the hooks below must read the sanitized carry (a copy, so later
+            # new_state updates cannot leak into the hooks' view of `state`)
+            state = dict(new_state)
 
     # sync='global': state updates run with psum-reduced statistics over the
     # data axes, so the carried state converges identically on every shard
@@ -223,7 +226,7 @@ def route(
     # outside shard_map) degrades to the plain per-batch update.
     global_axes = tuple(cfg.data_axes) if cfg.sync == "global" else ()
 
-    with jax.named_scope("router/score_adjust"):
+    with named_span("router/score_adjust"):
         adjusted = bal.score_adjust(
             s, state, cfg,
             token_mask=token_mask, axis_names=global_axes,
@@ -239,20 +242,22 @@ def route(
         corrected, pre_updates = adjusted
         hook_telemetry = {}
     new_state.update(pre_updates)
-    with jax.named_scope("router/select"):
+    with named_span("router/select"):
         w, idx = bal.select(s, corrected, cfg)
-    aux = bal.aux_loss(s, idx, cfg, token_mask)
-    with jax.named_scope("router/update_state"):
+    with named_span("router/aux_loss"):
+        aux = bal.aux_loss(s, idx, cfg, token_mask)
+    with named_span("router/update_state"):
         new_state.update(
             bal.update_state(
                 s, idx, state, cfg, token_mask=token_mask, axis_names=global_axes
             )
         )
-    metrics = dict(balancers.router_metrics(bal, s, w, idx, cfg))
-    metrics.update(hook_telemetry)
-    # dual-carry magnitude: every strategy carries 'q' (bias / dual price /
-    # log-correction), so its sup-norm is a universal health signal
-    metrics["q_abs_max"] = jnp.max(jnp.abs(new_state["q"]))
+    with named_span("router/metrics"):
+        metrics = dict(balancers.router_metrics(bal, s, w, idx, cfg))
+        metrics.update(hook_telemetry)
+        # dual-carry magnitude: every strategy carries 'q' (bias / dual
+        # price / log-correction), so its sup-norm is a universal health signal
+        metrics["q_abs_max"] = jnp.max(jnp.abs(new_state["q"]))
     return RouterOutput(
         combine_weights=w,
         expert_index=idx,

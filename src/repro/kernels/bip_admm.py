@@ -185,6 +185,7 @@ def iteration_on_transposed(
         # every step writes only its own blocks: steps are independent
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
+        name="bip_admm",
     )(st, q, lo, hi)
     # per-block counts are small exact integers in f32: the sum is exact
     return p[0], jnp.sum(cnt, axis=0)

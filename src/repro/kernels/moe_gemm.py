@@ -95,6 +95,7 @@ def grouped_gated_ffn_in(
             pltpu.VMEM((bc, bf), jnp.float32),
         ],
         interpret=interpret,
+        name="moe_gated_in",
     )(x, w_gate, w_up)
 
 
@@ -141,6 +142,7 @@ def grouped_matmul(
         out_shape=jax.ShapeDtypeStruct((e, c, d), h.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((bc, bd), jnp.float32)],
         interpret=interpret,
+        name="moe_matmul",
     )(h, w)
 
 

@@ -18,6 +18,7 @@ from jax import lax
 from repro.kernels import bip_admm as _bip
 from repro.kernels import moe_gemm as _gemm
 from repro.kernels.platform import interpret_default, varying_operands
+from repro.telemetry.trace import named_span
 
 
 @functools.partial(
@@ -119,16 +120,20 @@ def _expert_ffn_vjp(bc: int, bf: int, bd: int, interpret: bool):
     Forward is the fused Pallas pair (grouped_gated_ffn_in + grouped_matmul).
     Backward rematerializes the gate/up pre-activations and expresses every
     dgrad/wgrad as a grouped_matmul over transposed operands, so training
-    never falls back to differentiating through pallas_call.
+    never falls back to differentiating through pallas_call. Each phase has
+    its own scope under the caller's (`moe/gemm`): `fwd`, `bwd/remat`,
+    `bwd/dgrad` (dh, dx and the SwiGLU derivative between them) and
+    `bwd/wgrad` (dwd, dwg, dwu).
     """
     mm = functools.partial(_gemm.grouped_matmul, interpret=interpret)
 
     @jax.custom_vjp
     def f(x, wg, wu, wd):
-        h = _gemm.grouped_gated_ffn_in(
-            x, wg, wu, block_c=bc, block_f=bf, block_d=bd, interpret=interpret
-        )
-        return mm(h, wd, block_c=bc, block_d=bd, block_f=bf)
+        with named_span("fwd"):
+            h = _gemm.grouped_gated_ffn_in(
+                x, wg, wu, block_c=bc, block_f=bf, block_d=bd, interpret=interpret
+            )
+            return mm(h, wd, block_c=bc, block_d=bd, block_f=bf)
 
     def fwd(x, wg, wu, wd):
         return f(x, wg, wu, wd), (x, wg, wu, wd)
@@ -136,26 +141,27 @@ def _expert_ffn_vjp(bc: int, bf: int, bd: int, interpret: bool):
     def bwd(res, dy):
         x, wg, wu, wd = res
         t = lambda a: jnp.swapaxes(a, -1, -2)
-        # rematerialize pre-activations: residuals are just the inputs
-        g = mm(x, wg, block_c=bc, block_f=bd, block_d=bf)
-        u = mm(x, wu, block_c=bc, block_f=bd, block_d=bf)
-        gf = g.astype(jnp.float32)
-        uf = u.astype(jnp.float32)
-        sg = jax.nn.sigmoid(gf)
-        silu = gf * sg
-        h = (silu * uf).astype(x.dtype)
-        # dgrad/wgrad of the down projection
-        dh = mm(dy, t(wd), block_c=bc, block_f=bd, block_d=bf)
-        dwd = mm(t(h), dy, block_c=bf, block_f=bc, block_d=bd)
-        dhf = dh.astype(jnp.float32)
-        dg = (dhf * uf * (sg * (1.0 + gf * (1.0 - sg)))).astype(x.dtype)
-        du = (dhf * silu).astype(x.dtype)
-        # dgrad/wgrad of the fused gate/up projections
-        dx = mm(dg, t(wg), block_c=bc, block_f=bf, block_d=bd) + mm(
-            du, t(wu), block_c=bc, block_f=bf, block_d=bd
-        )
-        dwg = mm(t(x), dg, block_c=bd, block_f=bc, block_d=bf)
-        dwu = mm(t(x), du, block_c=bd, block_f=bc, block_d=bf)
+        with named_span("bwd/remat"):
+            # rematerialize pre-activations: residuals are just the inputs
+            g = mm(x, wg, block_c=bc, block_f=bd, block_d=bf)
+            u = mm(x, wu, block_c=bc, block_f=bd, block_d=bf)
+            gf = g.astype(jnp.float32)
+            uf = u.astype(jnp.float32)
+            sg = jax.nn.sigmoid(gf)
+            silu = gf * sg
+            h = (silu * uf).astype(x.dtype)
+        with named_span("bwd/dgrad"):
+            dh = mm(dy, t(wd), block_c=bc, block_f=bd, block_d=bf)
+            dhf = dh.astype(jnp.float32)
+            dg = (dhf * uf * (sg * (1.0 + gf * (1.0 - sg)))).astype(x.dtype)
+            du = (dhf * silu).astype(x.dtype)
+            dx = mm(dg, t(wg), block_c=bc, block_f=bf, block_d=bd) + mm(
+                du, t(wu), block_c=bc, block_f=bf, block_d=bd
+            )
+        with named_span("bwd/wgrad"):
+            dwd = mm(t(h), dy, block_c=bf, block_f=bc, block_d=bd)
+            dwg = mm(t(x), dg, block_c=bd, block_f=bc, block_d=bf)
+            dwu = mm(t(x), du, block_c=bd, block_f=bc, block_d=bf)
         return dx, dwg, dwu, dwd
 
     f.defvjp(fwd, bwd)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
+from repro.telemetry.trace import named_span
 
 Params = Dict[str, jnp.ndarray]
 
@@ -33,7 +34,13 @@ def init_rmsnorm(d: int, dtype) -> Params:
     return {"scale": jnp.ones((d,), dtype=dtype)}
 
 
+@named_span("norm")
 def rmsnorm(params: Params, x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
+    return _rmsnorm(params, x, eps)
+
+
+def _rmsnorm(params: Params, x: jnp.ndarray, eps: float) -> jnp.ndarray:
+    """rmsnorm without its layer scope, for the q/k norms inside `attn`."""
     dt = x.dtype
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
@@ -125,6 +132,7 @@ def causal_window_mask(
     return mask
 
 
+@named_span("attn")
 def attention(
     params: Params,
     x: jnp.ndarray,  # (B, S, d)
@@ -164,8 +172,8 @@ def attention(
     k = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(cfg.compute_dtype))
     v = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(cfg.compute_dtype))
     if cfg.qk_norm:
-        q = rmsnorm(params["q_norm"], q, cfg.rms_norm_eps)
-        k = rmsnorm(params["k_norm"], k, cfg.rms_norm_eps)
+        q = _rmsnorm(params["q_norm"], q, cfg.rms_norm_eps)
+        k = _rmsnorm(params["k_norm"], k, cfg.rms_norm_eps)
     q = apply_rope(q, positions, theta)
     k = apply_rope(k, positions, theta)
 
@@ -223,6 +231,7 @@ def attention(
     return jnp.einsum("bshk,hkd->bsd", y, params["wo"].astype(cfg.compute_dtype))
 
 
+@named_span("attn")
 def attention_chunk(
     params: Params,
     x: jnp.ndarray,  # (B, C, d)
@@ -288,8 +297,8 @@ def attention_chunk(
     k_new = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(cfg.compute_dtype))
     v_new = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(cfg.compute_dtype))
     if cfg.qk_norm:
-        q = rmsnorm(params["q_norm"], q, cfg.rms_norm_eps)
-        k_new = rmsnorm(params["k_norm"], k_new, cfg.rms_norm_eps)
+        q = _rmsnorm(params["q_norm"], q, cfg.rms_norm_eps)
+        k_new = _rmsnorm(params["k_norm"], k_new, cfg.rms_norm_eps)
     q = apply_rope(q, q_pos, theta)
     k_new = apply_rope(k_new, q_pos, theta)
 
@@ -392,8 +401,8 @@ def _attention_chunk_packed(
     k_new = jnp.einsum("bsd,dhk->bshk", x, params["wk"].astype(cfg.compute_dtype))
     v_new = jnp.einsum("bsd,dhk->bshk", x, params["wv"].astype(cfg.compute_dtype))
     if cfg.qk_norm:
-        q = rmsnorm(params["q_norm"], q, cfg.rms_norm_eps)
-        k_new = rmsnorm(params["k_norm"], k_new, cfg.rms_norm_eps)
+        q = _rmsnorm(params["q_norm"], q, cfg.rms_norm_eps)
+        k_new = _rmsnorm(params["k_norm"], k_new, cfg.rms_norm_eps)
     q = apply_rope(q, q_pos, theta)
     k_new = apply_rope(k_new, q_pos, theta)
 
@@ -525,10 +534,12 @@ def init_embedding(key, cfg: ModelConfig) -> Params:
     return p
 
 
+@named_span("embed")
 def embed(params: Params, tokens: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     return params["tok"].astype(cfg.compute_dtype)[tokens]
 
 
+@named_span("lm_head")
 def unembed(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     if cfg.tie_embeddings:
         logits = jnp.einsum(

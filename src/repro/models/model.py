@@ -28,6 +28,7 @@ from jax import lax
 from repro.configs.base import ModelConfig
 from repro.models import common, mamba2, moe, stack
 from repro.models.stack import MeshCtx
+from repro.telemetry.trace import named_span
 
 Params = Dict[str, Any]
 
@@ -213,11 +214,12 @@ class Model:
     ):
         logits, new_states, aux, mets = self.forward(params, batch, router_states, rng=rng)
         labels = batch["labels"]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        mask = (labels >= 0).astype(jnp.float32)
-        nll = jnp.where(labels >= 0, nll, 0.0)
-        ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        with named_span("lm_head"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+            mask = (labels >= 0).astype(jnp.float32)
+            nll = jnp.where(labels >= 0, nll, 0.0)
+            ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
         loss = ce + aux
         mets = dict(mets)
         mets.update(ce_loss=ce, aux_loss=aux, perplexity=jnp.exp(ce))
@@ -340,7 +342,6 @@ class Model:
             if "ck" in cache:
                 xq = common.rmsnorm(p["cross_norm"], x, cfg.rms_norm_eps)
                 dt = cfg.compute_dtype
-                q = jnp.einsum("bsd,dhk->bshk", xq, p["cross"]["wq"].astype(dt))
                 se = cache["ck"].shape[1]
                 if valid is None:
                     mask = jnp.ones((1, 1, x.shape[1], se), bool)
@@ -348,10 +349,12 @@ class Model:
                     mask = jnp.broadcast_to(
                         valid[:, None, :, None], (x.shape[0], 1, x.shape[1], se)
                     )
-                y = common._attend(q, cache["ck"], cache["cv"], mask, 0.0, dt)
-                x = x + jnp.einsum(
-                    "bshk,hkd->bsd", y, p["cross"]["wo"].astype(dt)
-                )
+                with named_span("attn"):
+                    q = jnp.einsum("bsd,dhk->bshk", xq, p["cross"]["wq"].astype(dt))
+                    y = common._attend(q, cache["ck"], cache["cv"], mask, 0.0, dt)
+                    x = x + jnp.einsum(
+                        "bshk,hkd->bsd", y, p["cross"]["wo"].astype(dt)
+                    )
         else:
             h, mcache = mamba2.mamba_chunk(
                 p["mamba"],
@@ -388,7 +391,8 @@ class Model:
             if cfg.dense_residual and "mlp" in p:
                 h = h + common.mlp(p["mlp"], xin, cfg)
             if cfg.n_shared_experts and "shared_mlp" in p:
-                h = h + common.mlp(p["shared_mlp"], xin, cfg)
+                with named_span("moe/shared"):
+                    h = h + common.mlp(p["shared_mlp"], xin, cfg)
             x = x + h
 
         if mixer_kind.endswith("+shared"):

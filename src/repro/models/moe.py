@@ -168,6 +168,7 @@ def _dispatch_plan(
     return pos, keep
 
 
+@named_span("moe/gemm")
 def _expert_ffn(
     w_gate: jnp.ndarray,  # (e, d, f)
     w_up: jnp.ndarray,
@@ -212,15 +213,13 @@ def moe_ffn_local(
     cap = expert_capacity(n, cfg)
     rcfg = router_config(cfg, data_axes=())
 
-    logits = jnp.einsum("nd,dm->nm", x.astype(jnp.float32), params["w_router"])
+    with named_span("router/scores"):
+        logits = jnp.einsum("nd,dm->nm", x.astype(jnp.float32), params["w_router"])
     out = route(logits, router_state, rcfg, token_mask=token_mask)
     with named_span("moe/dispatch"):
         plan = make_dispatch_plan(out.expert_index, m, cap, token_mask)
         buf = plan.pack(x)  # (m, cap, d) by gather — no one-hot, no scatter
-    with named_span("moe/gemm"):
-        y = _expert_ffn(
-            params["w_gate"], params["w_up"], params["w_down"], buf, cfg
-        )
+    y = _expert_ffn(params["w_gate"], params["w_up"], params["w_down"], buf, cfg)
     with named_span("moe/combine"):
         y_tok = plan.combine(y, out.combine_weights)
 
@@ -304,19 +303,21 @@ def moe_ffn_ep2d(
         else:
             x_all = x_loc  # already replicated
             mask_all = mask_loc
-        logits = jnp.einsum("nd,dm->nm", x_all.astype(jnp.float32), w_router)
+        with named_span("router/scores"):
+            logits = jnp.einsum("nd,dm->nm", x_all.astype(jnp.float32), w_router)
         out = route(logits, q_state, rcfg, token_mask=mask_all)
-        plan = make_dispatch_plan(out.expert_index, m, cap, mask_all)
-
-        # gather THIS rank's expert segments straight out of the sort order
-        buf = plan.pack(x_all, expert_offset=rank * m_loc, n_local=m_loc)
+        with named_span("moe/dispatch"):
+            plan = make_dispatch_plan(out.expert_index, m, cap, mask_all)
+            # gather THIS rank's expert segments straight out of the sort order
+            buf = plan.pack(x_all, expert_offset=rank * m_loc, n_local=m_loc)
 
         # expert FFN on the local (m_loc, f_loc) weight shard; y is partial
         # over f, completed by the psum below
         y = _expert_ffn(w_gate, w_up, w_down, buf, cfg)
 
-        y_tok = plan.combine(y, out.combine_weights, expert_offset=rank * m_loc)
-        y_tok = lax.psum(y_tok, model_axis)
+        with named_span("moe/combine"):
+            y_tok = plan.combine(y, out.combine_weights, expert_offset=rank * m_loc)
+            y_tok = lax.psum(y_tok, model_axis)
         if token_sharded:
             if f_shards > 1:
                 y_tok = lax.psum_scatter(
@@ -458,31 +459,31 @@ def moe_ffn_ep2ds(
     def block(x_loc, w_router, w_gate, w_up, w_down, q_state, *mask_args):
         rank = lax.axis_index(model_axis)
         mask_loc = mask_args[0] if mask_args else None
-        logits = jnp.einsum("nd,dm->nm", x_loc.astype(jnp.float32), w_router)
+        with named_span("router/scores"):
+            logits = jnp.einsum("nd,dm->nm", x_loc.astype(jnp.float32), w_router)
         out = route(logits, q_state, rcfg, token_mask=mask_loc)
-        plan = make_dispatch_plan(out.expert_index, m, cap, mask_loc)
-
-        buf = plan.pack(x_loc, expert_offset=rank * m_loc, n_local=m_loc)
-
-        # selective gather: only dispatched tokens cross the data axis
-        buf_all = lax.all_gather(buf, data_axes, axis=1, tiled=True)
-        # (m_loc, n_data * cap, d)
+        with named_span("moe/dispatch"):
+            plan = make_dispatch_plan(out.expert_index, m, cap, mask_loc)
+            buf = plan.pack(x_loc, expert_offset=rank * m_loc, n_local=m_loc)
+            # selective gather: only dispatched tokens cross the data axis
+            buf_all = lax.all_gather(buf, data_axes, axis=1, tiled=True)
+            # (m_loc, n_data * cap, d)
 
         y = _expert_ffn(w_gate, w_up, w_down, buf_all, cfg)
 
-        if f_sharded:
-            # y is partial over f: sum partials and hand every source rank
-            # its own slice back in one collective
-            y = lax.psum_scatter(y, data_axes, scatter_dimension=1, tiled=True)
-        else:
-            # weights were replicated over data: y is complete; just take
-            # this rank's slice of the gathered axis
-            idx = _flat_axis_index(mesh, data_axes)
-            y = lax.dynamic_slice_in_dim(y, idx * cap, cap, axis=1)
-        # (m_loc, cap, d), complete values for THIS rank's dispatched tokens
-
-        y_tok = plan.combine(y, out.combine_weights, expert_offset=rank * m_loc)
-        y_tok = lax.psum(y_tok, model_axis)
+        with named_span("moe/combine"):
+            if f_sharded:
+                # y is partial over f: sum partials and hand every source rank
+                # its own slice back in one collective
+                y = lax.psum_scatter(y, data_axes, scatter_dimension=1, tiled=True)
+            else:
+                # weights were replicated over data: y is complete; just take
+                # this rank's slice of the gathered axis
+                idx = _flat_axis_index(mesh, data_axes)
+                y = lax.dynamic_slice_in_dim(y, idx * cap, cap, axis=1)
+            # (m_loc, cap, d), complete values for THIS rank's dispatched tokens
+            y_tok = plan.combine(y, out.combine_weights, expert_offset=rank * m_loc)
+            y_tok = lax.psum(y_tok, model_axis)
 
         # global sync: the whole state dict (q + forecaster EMAs) converged
         # identically per shard (vma-replicated, no averaging); local sync:
@@ -581,18 +582,20 @@ def moe_ffn_ep(
         # x_loc: (n_loc, d); w_gate: (m_loc, d, f); q_state: {'q': (m,)}
         rank = lax.axis_index(model_axis)
         mask_loc = mask_args[0] if mask_args else None
-        logits = jnp.einsum("nd,dm->nm", x_loc.astype(jnp.float32), w_router)
+        with named_span("router/scores"):
+            logits = jnp.einsum("nd,dm->nm", x_loc.astype(jnp.float32), w_router)
         out = route(logits, q_state, rcfg, token_mask=mask_loc)
-        plan = make_dispatch_plan(out.expert_index, m, cap, mask_loc)
-
-        # pack only the slots routed to THIS rank's experts (pure gather)
-        buf = plan.pack(x_loc, expert_offset=rank * m_loc, n_local=m_loc)
+        with named_span("moe/dispatch"):
+            plan = make_dispatch_plan(out.expert_index, m, cap, mask_loc)
+            # pack only the slots routed to THIS rank's experts (pure gather)
+            buf = plan.pack(x_loc, expert_offset=rank * m_loc, n_local=m_loc)
 
         y = _expert_ffn(w_gate, w_up, w_down, buf, cfg)
 
-        y_tok = plan.combine(y, out.combine_weights, expert_offset=rank * m_loc)
-        # combine across expert-owners (rides the TP all-reduce)
-        y_tok = lax.psum(y_tok, model_axis)
+        with named_span("moe/combine"):
+            y_tok = plan.combine(y, out.combine_weights, expert_offset=rank * m_loc)
+            # combine across expert-owners (rides the TP all-reduce)
+            y_tok = lax.psum(y_tok, model_axis)
 
         # router state: sync='global' duals already converged identically on
         # every shard (psum'd order statistics inside route, vma-replicated);

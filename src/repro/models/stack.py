@@ -31,6 +31,7 @@ from jax import lax
 from repro.configs.base import ModelConfig
 from repro.models import common, mamba2, moe
 from repro.core.types import init_router_state
+from repro.telemetry.trace import named_span
 
 Params = Dict[str, Any]
 
@@ -181,7 +182,8 @@ def apply_layer(
         if cfg.dense_residual and "mlp" in p:
             h = h + common.mlp(p["mlp"], xin, cfg)
         if cfg.n_shared_experts and "shared_mlp" in p:
-            h = h + common.mlp(p["shared_mlp"], xin, cfg)
+            with named_span("moe/shared"):
+                h = h + common.mlp(p["shared_mlp"], xin, cfg)
         x = x + h
         router_state = new_state
         aux = aux + aux_moe
@@ -220,6 +222,7 @@ def apply_layer(
     return x, router_state, aux, mets
 
 
+@named_span("attn")
 def _cross_attention(p, x, enc_out, cfg: ModelConfig, *, mesh_ctx: MeshCtx = MeshCtx()):
     """Cross attention, decoder-query-chunked (same memory discipline as
     self-attention: one (chunk, S_enc) score block at a time, or the whole

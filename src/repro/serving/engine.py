@@ -446,73 +446,70 @@ class ContinuousBatchingEngine:
             time.sleep(self.step_delay)  # slow_step fault injection
         if self._profiler is not None:
             self._profiler.step(self.telemetry.n_steps)
-        now = self.clock()
-        # sweep BEFORE admission: evicting overdue slots frees them for
-        # waiting work within the same step
-        dropped = [
-            self._observe(r)
-            for r in self.scheduler.expire(now) + self.scheduler.take_dropped()
-        ]
-        for slot_idx, _req in self.scheduler.admit(now):
-            self.cache = self._reset(self.cache, jnp.asarray(slot_idx))
-
-        b, c = self.n_slots, self.chunk_size
-        active = list(self.scheduler.active())
+        # host phases as spans: admit, plan, dispatch, fetch (the wait on
+        # the device), observe; each carries the step index, and those after
+        # the plan its prefill and decode token counts
+        step = self.telemetry.n_steps
+        with trace_span("serve/admit", step=step):
+            now = self.clock()
+            # sweep BEFORE admission: evicting overdue slots frees them for
+            # waiting work within the same step
+            dropped = [
+                self._observe(r)
+                for r in self.scheduler.expire(now) + self.scheduler.take_dropped()
+            ]
+            for slot_idx, _req in self.scheduler.admit(now):
+                self.cache = self._reset(self.cache, jnp.asarray(slot_idx))
+            active = list(self.scheduler.active())
         if not active:
             return dropped
 
-        packed = self._plan_packed(active) if self._can_pack else None
-        self._rng, sub = jax.random.split(self._rng)
-        if packed is not None:
-            (tokens, positions, segments, write_slots, cache_rows,
-             gather_rows, gather_cols, plan) = packed
-            with trace_span("serve/step"):
-                nxt, self.cache, self.router_states, mets = (
-                    self._serve_step_packed(
-                        self.params,
-                        self.cache,
-                        self.router_states,
-                        jnp.asarray(tokens),
-                        jnp.asarray(positions),
-                        jnp.asarray(segments),
-                        jnp.asarray(write_slots),
-                        jnp.asarray(cache_rows),
-                        jnp.asarray(gather_rows),
-                        jnp.asarray(gather_cols),
-                        sub,
-                    )
-                )
-                nxt = np.asarray(nxt)
-        else:
-            tokens = np.zeros((b, c), np.int32)
-            lengths = np.zeros((b,), np.int32)
-            plan = []  # (slot_idx, slot, kind, n_tokens)
-            for i, slot in active:
-                req = slot.request
-                if not slot.prompt_done:
-                    chunk = req.prompt[slot.n_prefilled : slot.n_prefilled + c]
-                    tokens[i, : len(chunk)] = chunk
-                    lengths[i] = len(chunk)
-                    plan.append((i, slot, PREFILL, len(chunk)))
-                else:
-                    tokens[i, 0] = req.output[-1]
-                    lengths[i] = 1
-                    plan.append((i, slot, DECODE, 1))
-            with trace_span("serve/step"):
-                nxt, self.cache, self.router_states, mets = self._serve_step(
-                    self.params,
-                    self.cache,
-                    self.router_states,
-                    jnp.asarray(tokens),
-                    jnp.asarray(lengths),
-                    sub,
-                )
-                nxt = np.asarray(nxt)
+        b, c = self.n_slots, self.chunk_size
+        with trace_span("serve/plan", step=step):
+            packed = self._plan_packed(active) if self._can_pack else None
+            self._rng, sub = jax.random.split(self._rng)
+            if packed is not None:
+                (tokens, positions, segments, write_slots, cache_rows,
+                 gather_rows, gather_cols, plan) = packed
+                args = (jnp.asarray(tokens), jnp.asarray(positions),
+                        jnp.asarray(segments), jnp.asarray(write_slots),
+                        jnp.asarray(cache_rows), jnp.asarray(gather_rows),
+                        jnp.asarray(gather_cols), sub)
+                serve_step = self._serve_step_packed
+            else:
+                tokens = np.zeros((b, c), np.int32)
+                lengths = np.zeros((b,), np.int32)
+                plan = []  # (slot_idx, slot, kind, n_tokens)
+                for i, slot in active:
+                    req = slot.request
+                    if not slot.prompt_done:
+                        chunk = req.prompt[slot.n_prefilled : slot.n_prefilled + c]
+                        tokens[i, : len(chunk)] = chunk
+                        lengths[i] = len(chunk)
+                        plan.append((i, slot, PREFILL, len(chunk)))
+                    else:
+                        tokens[i, 0] = req.output[-1]
+                        lengths[i] = 1
+                        plan.append((i, slot, DECODE, 1))
+                args = (jnp.asarray(tokens), jnp.asarray(lengths), sub)
+                serve_step = self._serve_step
+            counts = {
+                "n_prefill": sum(n for _, _, kind, n in plan if kind == PREFILL),
+                "n_decode": sum(1 for _, _, kind, _ in plan if kind == DECODE),
+            }
+        with trace_span("serve/dispatch", step=step, **counts):
+            nxt, self.cache, self.router_states, mets = serve_step(
+                self.params, self.cache, self.router_states, *args
+            )
+        with trace_span("serve/fetch", step=step, **counts):
+            nxt = np.asarray(nxt)
+        with trace_span("serve/observe", step=step, **counts):
+            return self._finish_step(dropped, plan, mets, nxt, counts)
+
+    def _finish_step(self, dropped, plan, mets, nxt, counts) -> List[Request]:
+        """Record the step and hand each planned slot its sampled token."""
         self.telemetry.on_step(
-            mets,
-            n_prefill=sum(n for _, _, kind, n in plan if kind == PREFILL),
-            n_decode=sum(1 for _, _, kind, _ in plan if kind == DECODE),
-            queue_depth=len(self.scheduler.waiting),
+            mets, queue_depth=len(self.scheduler.waiting), **counts
         )
 
         done: List[Request] = dropped

@@ -23,7 +23,6 @@ from repro.telemetry.sinks import (
     CSVSink,
     JSONLSink,
     MemorySink,
-    MultiSink,
     Sink,
     open_sink,
 )
@@ -37,7 +36,6 @@ __all__ = [
     "MemorySink",
     "MetricSeries",
     "MetricStream",
-    "MultiSink",
     "Profiler",
     "ServingTelemetry",
     "Sink",

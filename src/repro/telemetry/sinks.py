@@ -12,7 +12,7 @@ serving SLO tracker, guard events) shares one export path:
 crash-tolerant: a torn final line is ignorable). `CSVSink` flattens records
 onto a fixed header inferred from the first record of each kind (one file
 per kind, since train steps and serve requests share no columns).
-`MemorySink` backs tests and the terminal reporter. `MultiSink` fans out.
+`MemorySink` backs tests and the terminal reporter.
 
 `open_sink(path)` resolves a writer by extension so launchers need one flag.
 """
@@ -133,22 +133,6 @@ class CSVSink(Sink):
         super().close()
 
 
-class MultiSink(Sink):
-    """Fan one emit out to several sinks."""
-
-    def __init__(self, *sinks: Sink):
-        self.sinks = [s for s in sinks if s is not None]
-
-    def emit(self, record: Dict[str, Any]) -> None:
-        for s in self.sinks:
-            s.emit(record)
-
-    def close(self) -> None:
-        for s in self.sinks:
-            s.close()
-        super().close()
-
-
 def open_sink(path: Optional[str]) -> Optional[Sink]:
     """Resolve a sink from a launcher --telemetry path (None passes through)."""
     if path is None:
@@ -162,7 +146,6 @@ __all__ = [
     "CSVSink",
     "JSONLSink",
     "MemorySink",
-    "MultiSink",
     "Sink",
     "open_sink",
 ]

@@ -61,6 +61,7 @@ from repro.core.metrics import BalanceTracker
 from repro.models.model import Model
 from repro.optim import adamw as _adamw
 from repro.telemetry.metrics import MetricSeries, TrainTelemetry
+from repro.telemetry.trace import named_span
 
 
 @jax.tree_util.register_dataclass
@@ -163,14 +164,14 @@ def make_train_step(
                 loss = loss * nan_coef
             return loss, aux
 
-        with jax.named_scope("train/fwd_bwd"):
+        with named_span("train/fwd_bwd"):
             return jax.value_and_grad(f, has_aux=True)(params)
 
     def _apply(state: TrainState, grads, new_router, mets, lr_scale=None):
         lr = lr_fn(state.opt_state["step"].astype(jnp.float32))
         if lr_scale is not None:
             lr = lr * lr_scale
-        with jax.named_scope("train/apply"):
+        with named_span("train/apply"):
             new_params, new_opt, info = _adamw.adamw_update(
                 grads, state.opt_state, state.params, lr, opt_cfg
             )
@@ -311,6 +312,8 @@ def compile_train_step(
             buf = stream.accumulate(buf, mets, step_idx)
             return new_state, mets, buf
 
+    # one program name for every variant: traces and build spans name it so
+    step.__name__ = step.__qualname__ = "train_step"
     if mesh is None:
         return jax.jit(step, donate_argnums=donate_argnums)
 

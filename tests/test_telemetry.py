@@ -339,4 +339,4 @@ def test_bench_run_unknown_benchmark_lists_registry(capsys):
     assert exc.value.code == 2
     err = capsys.readouterr().err
     assert "unknown benchmark" in err
-    assert "telemetry_overhead" in err and "paper_repro" in err
+    assert "serve_throughput" in err and "paper_repro" in err
