@@ -55,7 +55,7 @@ def _kernel_microbench():
     wg = jnp.asarray(rng.standard_normal((ee, d, f)).astype(np.float32)) * 0.1
     wu = jnp.asarray(rng.standard_normal((ee, d, f)).astype(np.float32)) * 0.1
     wd = jnp.asarray(rng.standard_normal((ee, f, d)).astype(np.float32)) * 0.1
-    fn2 = jax.jit(lambda *a: ops.expert_ffn(*a, block_c=64, block_f=128, block_d=64))
+    fn2 = jax.jit(ops.expert_ffn)
     fn2(x, wg, wu, wd).block_until_ready()
     t0 = time.perf_counter()
     for _ in range(3):

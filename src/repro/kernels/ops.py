@@ -105,35 +105,26 @@ def _round_up(v: int, mult: int) -> int:
     return -(-v // mult) * mult
 
 
-def _pick_block(dim: int, want: int) -> int:
-    """Largest usable block ≤ `want` that divides `dim` (dim is a multiple
-    of 128 after padding; non-dividing requests fall back to one MXU tile)."""
-    if dim % want == 0:
-        return min(want, dim)
-    return min(128, dim)
-
-
 @functools.lru_cache(maxsize=None)
-def _expert_ffn_vjp(bc: int, bf: int, bd: int, interpret: bool):
-    """custom_vjp'd grouped FFN at fixed (aligned) block shapes.
+def _expert_ffn_vjp(interpret: bool):
+    """custom_vjp'd grouped FFN over MXU-aligned (padded) shapes.
 
     Forward is the fused Pallas pair (grouped_gated_ffn_in + grouped_matmul).
     Backward rematerializes the gate/up pre-activations and expresses every
-    dgrad/wgrad as a grouped_matmul over transposed operands, so training
-    never falls back to differentiating through pallas_call. Each phase has
-    its own scope under the caller's (`moe/gemm`): `fwd`, `bwd/remat`,
-    `bwd/dgrad` (dh, dx and the SwiGLU derivative between them) and
-    `bwd/wgrad` (dwd, dwg, dwu).
+    dgrad/wgrad as a grouped_matmul (the wgrads read the stored activations
+    transposed), so training never falls back to differentiating through
+    pallas_call. Each product picks its own tiles from its shape
+    (moe_gemm.pick_tiles). Each phase has its own scope under the caller's
+    (`moe/gemm`): `fwd`, `bwd/remat`, `bwd/dgrad` (dh, dx and the SwiGLU
+    derivative between them) and `bwd/wgrad` (dwd, dwg, dwu).
     """
     mm = functools.partial(_gemm.grouped_matmul, interpret=interpret)
 
     @jax.custom_vjp
     def f(x, wg, wu, wd):
         with named_span("fwd"):
-            h = _gemm.grouped_gated_ffn_in(
-                x, wg, wu, block_c=bc, block_f=bf, block_d=bd, interpret=interpret
-            )
-            return mm(h, wd, block_c=bc, block_d=bd, block_f=bf)
+            h = _gemm.grouped_gated_ffn_in(x, wg, wu, interpret=interpret)
+            return mm(h, wd)
 
     def fwd(x, wg, wu, wd):
         return f(x, wg, wu, wd), (x, wg, wu, wd)
@@ -143,25 +134,23 @@ def _expert_ffn_vjp(bc: int, bf: int, bd: int, interpret: bool):
         t = lambda a: jnp.swapaxes(a, -1, -2)
         with named_span("bwd/remat"):
             # rematerialize pre-activations: residuals are just the inputs
-            g = mm(x, wg, block_c=bc, block_f=bd, block_d=bf)
-            u = mm(x, wu, block_c=bc, block_f=bd, block_d=bf)
+            g = mm(x, wg)
+            u = mm(x, wu)
             gf = g.astype(jnp.float32)
             uf = u.astype(jnp.float32)
             sg = jax.nn.sigmoid(gf)
             silu = gf * sg
             h = (silu * uf).astype(x.dtype)
         with named_span("bwd/dgrad"):
-            dh = mm(dy, t(wd), block_c=bc, block_f=bd, block_d=bf)
+            dh = mm(dy, t(wd))
             dhf = dh.astype(jnp.float32)
             dg = (dhf * uf * (sg * (1.0 + gf * (1.0 - sg)))).astype(x.dtype)
             du = (dhf * silu).astype(x.dtype)
-            dx = mm(dg, t(wg), block_c=bc, block_f=bf, block_d=bd) + mm(
-                du, t(wu), block_c=bc, block_f=bf, block_d=bd
-            )
+            dx = mm(dg, t(wg)) + mm(du, t(wu))
         with named_span("bwd/wgrad"):
-            dwd = mm(t(h), dy, block_c=bf, block_f=bc, block_d=bd)
-            dwg = mm(t(x), dg, block_c=bd, block_f=bc, block_d=bf)
-            dwu = mm(t(x), du, block_c=bd, block_f=bc, block_d=bf)
+            dwd = mm(h, dy, trans_a=True)
+            dwg = mm(x, dg, trans_a=True)
+            dwu = mm(x, du, trans_a=True)
         return dx, dwg, dwu, dwd
 
     f.defvjp(fwd, bwd)
@@ -175,9 +164,6 @@ def expert_ffn(
     w_down: jnp.ndarray,  # (E, F, D)
     *,
     interpret: Optional[bool] = None,
-    block_c: int = 128,
-    block_f: int = 256,
-    block_d: int = 256,
 ) -> jnp.ndarray:
     """Differentiable grouped expert FFN with automatic MXU alignment.
 
@@ -196,22 +182,11 @@ def expert_ffn(
     e, c, d = x.shape
     f = w_gate.shape[-1]
     cp, dp, fp = _round_up(c, 128), _round_up(d, 128), _round_up(f, 128)
-    bc = _pick_block(cp, block_c)
-    bd = _pick_block(dp, block_d)
-    bf = _pick_block(fp, block_f)
 
     def pad(a, rows, cols):
         return jnp.pad(a, ((0, 0), (0, rows - a.shape[1]), (0, cols - a.shape[2])))
 
-    y = _expert_ffn_vjp(bc, bf, bd, bool(interpret))(
+    y = _expert_ffn_vjp(bool(interpret))(
         pad(x, cp, dp), pad(w_gate, dp, fp), pad(w_up, dp, fp), pad(w_down, fp, dp)
     )
     return y[:, :c, :d]
-
-
-def grouped_matmul(h, w, *, interpret: Optional[bool] = None, **block_kw):
-    return _gemm.grouped_matmul(h, w, interpret=interpret, **block_kw)
-
-
-def grouped_gated_ffn_in(x, wg, wu, *, interpret: Optional[bool] = None, **block_kw):
-    return _gemm.grouped_gated_ffn_in(x, wg, wu, interpret=interpret, **block_kw)

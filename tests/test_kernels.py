@@ -197,21 +197,82 @@ def test_grouped_gated_ffn_in_allclose(e, c, d, f):
     x = jnp.asarray(rng.standard_normal((e, c, d)).astype(np.float32)) * 0.3
     wg = jnp.asarray(rng.standard_normal((e, d, f)).astype(np.float32)) * 0.1
     wu = jnp.asarray(rng.standard_normal((e, d, f)).astype(np.float32)) * 0.1
-    got = moe_gemm.grouped_gated_ffn_in(x, wg, wu, block_c=64, block_f=64, block_d=32)
+    got = moe_gemm.grouped_gated_ffn_in(x, wg, wu, tiles=moe_gemm.Tiles(64, 32, 64))
     want = ref.gated_ffn_in_ref(x, wg, wu)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
 @pytest.mark.parametrize(
-    "e,c,f,d", [(4, 128, 64, 128), (2, 64, 128, 64)]
+    "e,c,f,d,trans_a",
+    [
+        pytest.param(4, 128, 64, 128, False, id="4-128-64-128"),
+        pytest.param(2, 64, 128, 64, False, id="2-64-128-64"),
+        pytest.param(4, 128, 64, 128, True, id="4-128-64-128-trans_a"),
+        pytest.param(2, 64, 128, 64, True, id="2-64-128-64-trans_a"),
+    ],
 )
-def test_grouped_matmul_allclose(e, c, f, d):
+def test_grouped_matmul_allclose(e, c, f, d, trans_a):
+    """Blocks smaller than the shape (a split contraction accumulates over
+    grid steps); with trans_a the left operand is read as stored (F, C)."""
     rng = np.random.default_rng(1)
     h = jnp.asarray(rng.standard_normal((e, c, f)).astype(np.float32)) * 0.3
     w = jnp.asarray(rng.standard_normal((e, f, d)).astype(np.float32)) * 0.1
-    got = moe_gemm.grouped_matmul(h, w, block_c=64, block_d=64, block_f=32)
+    a = jnp.swapaxes(h, 1, 2) if trans_a else h
+    got = moe_gemm.grouped_matmul(a, w, trans_a=trans_a, tiles=moe_gemm.Tiles(64, 32, 64))
     want = ref.grouped_matmul_ref(h, w)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# (experts, capacity, d_model, d_ff) the model path runs the kernels at:
+# minimind-moe-16e and -64e training, a decode step (capacity padded to 128),
+# and the small shapes of the tests here
+PICK_SHAPES = {
+    "16e": (16, 2560, 512, 1408),
+    "64e": (64, 1280, 512, 1408),
+    "decode": (16, 128, 512, 1408),
+    "small": (3, 40, 96, 200),
+    "one_row": (1, 1, 32, 48),
+    "f1408_c640": (2, 640, 128, 1408),
+}
+# each grouped product of ops.expert_ffn as (rows, contraction, cols, right
+# operands, transposed left operand) of the padded (c, d, f)
+PHASES = {
+    "fwd_in": lambda c, d, f: (c, d, f, 2, False),
+    "fwd_out": lambda c, d, f: (c, f, d, 1, False),
+    "remat": lambda c, d, f: (c, d, f, 1, False),
+    "dgrad_dh": lambda c, d, f: (c, d, f, 1, False),
+    "dgrad_dx": lambda c, d, f: (c, f, d, 1, False),
+    "wgrad_dwd": lambda c, d, f: (f, c, d, 1, True),
+    "wgrad_dwg": lambda c, d, f: (d, c, f, 1, True),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("phase", sorted(PHASES))
+@pytest.mark.parametrize("shape", sorted(PICK_SHAPES))
+def test_pick_tiles_aligned_dividing_and_in_budget(shape, phase, dtype):
+    """Every block is a multiple of 128 or its whole dimension, divides the
+    padded dimension, and the step fits the budget the picker states."""
+    _, c, d, f = PICK_SHAPES[shape]
+    pad = lambda v: -(-v // 128) * 128
+    m, k, n, n_rhs, trans_a = PHASES[phase](pad(c), pad(d), pad(f))
+    t = moe_gemm.pick_tiles(m, k, n, dtype, n_rhs=n_rhs, trans_a=trans_a)
+    for block, dim in zip(t, (m, k, n)):
+        assert block % 128 == 0 or block == dim, (t, m, k, n)
+        assert dim % block == 0, (t, m, k, n)
+    itemsize = jnp.dtype(dtype).itemsize
+    assert moe_gemm.vmem_bytes(t, k, itemsize, n_rhs, trans_a) <= moe_gemm.VMEM_BUDGET
+
+
+def test_pick_tiles_takes_whole_dims_at_train_16e():
+    """At the 16e training widths a bf16 product takes F = 1408 and the
+    D = 512 contraction whole (no 128-wide fallback, no accumulator loop),
+    so each forward kernel runs a few dozen grid steps, not thousands."""
+    t = moe_gemm.pick_tiles(2560, 512, 1408, jnp.bfloat16, n_rhs=2)
+    assert (t.bk, t.bn) == (512, 1408)
+    assert 16 * (2560 // t.bm) <= 64
+    t = moe_gemm.pick_tiles(2560, 1408, 512, jnp.bfloat16)
+    assert (t.bk, t.bn) == (1408, 512)
 
 
 @pytest.mark.parametrize(
@@ -249,16 +310,36 @@ def test_ops_expert_ffn_custom_vjp_grads_match_einsum():
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 3e-5), (jnp.bfloat16, 3e-2)])
-def test_expert_ffn_dtype_sweep(dtype, atol):
+@pytest.mark.parametrize(
+    "dtype,shape,atol",
+    [
+        pytest.param(jnp.float32, (2, 128, 64, 128), 3e-5, id="float32-3e-05"),
+        pytest.param(jnp.bfloat16, (2, 128, 64, 128), 3e-2, id="bfloat16-0.03"),
+        # F = 11 x 128 and C = 5 x 128: blocks of the whole dimension
+        pytest.param(jnp.bfloat16, (2, 640, 128, 1408), 3e-2, id="bfloat16-0.03-f1408-c640"),
+    ],
+)
+def test_expert_ffn_dtype_sweep(dtype, shape, atol):
+    """ops.expert_ffn on each operand dtype against the einsum reference
+    computed in f32 from the same values: the forward to `atol`, and the
+    custom-VJP gradients of every operand to 1e-4 (f32) or 1e-2 (bf16,
+    which rounds h, dg and du between products) of each one's largest
+    entry."""
     rng = np.random.default_rng(2)
-    e, c, d, f = 2, 128, 64, 128
+    e, c, d, f = shape
     x = jnp.asarray(rng.standard_normal((e, c, d)), dtype) * 0.3
     wg = jnp.asarray(rng.standard_normal((e, d, f)), dtype) * 0.1
     wu = jnp.asarray(rng.standard_normal((e, d, f)), dtype) * 0.1
     wd = jnp.asarray(rng.standard_normal((e, f, d)), dtype) * 0.1
-    got = moe_gemm.expert_ffn(x, wg, wu, wd, block_c=64, block_f=64, block_d=32)
+    got = ops.expert_ffn(x, wg, wu, wd)
     want = ref.expert_ffn_ref(x, wg, wu, wd)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), atol=atol
     )
+    loss = lambda fn: lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
+    g_k = jax.grad(loss(ops.expert_ffn), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    g_r = jax.grad(loss(ref.expert_ffn_ref), argnums=(0, 1, 2, 3))(x, wg, wu, wd)
+    tol = 1e-4 if dtype == jnp.float32 else 1e-2
+    for a, b in zip(g_k, g_r):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=tol * np.abs(b).max())

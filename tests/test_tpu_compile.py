@@ -22,10 +22,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 from repro.kernels import bip_admm, moe_gemm, ops
 
 # (experts, capacity, d_model, d_ff): minimind-moe-16e / -64e expert widths at
-# the capacity of one 8192-token sequence (capacity_factor 1.25). d_ff = 1408
-# is not a multiple of the default 256-wide f block, so the raw kernels get
-# block_f=128 — the block ops.expert_ffn picks for the model path.
-FFN_WIDTHS = {"16e": (16, 2560, 512, 1408), "64e": (64, 1280, 512, 1408)}
+# the capacity of one 8192-token sequence (capacity_factor 1.25), and a 16e
+# decode step, whose capacity ops.expert_ffn pads to 128. The kernels take the
+# tiles moe_gemm.pick_tiles gives each shape, so a picked tile that overruns
+# Mosaic's scoped VMEM fails here.
+FFN_WIDTHS = {
+    "16e": (16, 2560, 512, 1408),
+    "64e": (64, 1280, 512, 1408),
+    "16e_decode": (16, 128, 512, 1408),
+}
 # (experts, top_k, tokens of one 2 x 8192 training step)
 DUAL_WIDTHS = {"16e": (16, 4, 16384), "64e": (64, 8, 16384)}
 
@@ -65,9 +70,7 @@ def _has_kernel(fn, *args) -> bool:
 @pytest.mark.parametrize("width", sorted(FFN_WIDTHS))
 def test_grouped_gated_ffn_in_compiles(one_chip, width):
     e, c, d, f = FFN_WIDTHS[width]
-    fn = lambda x, wg, wu: moe_gemm.grouped_gated_ffn_in(
-        x, wg, wu, block_f=128, interpret=False
-    )
+    fn = lambda x, wg, wu: moe_gemm.grouped_gated_ffn_in(x, wg, wu, interpret=False)
     assert _has_kernel(
         fn, _sds((e, c, d), one_chip), _sds((e, d, f), one_chip), _sds((e, d, f), one_chip)
     )
@@ -76,8 +79,16 @@ def test_grouped_gated_ffn_in_compiles(one_chip, width):
 @pytest.mark.parametrize("width", sorted(FFN_WIDTHS))
 def test_grouped_matmul_compiles(one_chip, width):
     e, c, d, f = FFN_WIDTHS[width]
-    fn = lambda h, w: moe_gemm.grouped_matmul(h, w, block_f=128, interpret=False)
+    fn = lambda h, w: moe_gemm.grouped_matmul(h, w, interpret=False)
     assert _has_kernel(fn, _sds((e, c, f), one_chip), _sds((e, f, d), one_chip))
+
+
+@pytest.mark.parametrize("width", sorted(FFN_WIDTHS))
+def test_grouped_matmul_transposed_lhs_compiles(one_chip, width):
+    """The wgrad form h^T @ dy, reading h as stored."""
+    e, c, d, f = FFN_WIDTHS[width]
+    fn = lambda h, dy: moe_gemm.grouped_matmul(h, dy, trans_a=True, interpret=False)
+    assert _has_kernel(fn, _sds((e, c, f), one_chip), _sds((e, c, d), one_chip))
 
 
 @pytest.mark.parametrize("width", sorted(FFN_WIDTHS))
