@@ -5,11 +5,16 @@ program under test, so a change to the program cannot change what is counted.
 
 * `PEAKS` -- published peaks per `jax.Device.device_kind`. A kind that is not
   in the table is an error, never a default.
-* `matmul_params_per_token` -- parameters a token multiplies through (the
-  active experts only), the N of MFU's 6·N.
+* `matmul_params_per_token`, `attention_flops_per_token`, `moe_layers` --
+  the architecture's own counts, which the plain reference that the
+  configuration names exports (bench/refs/<model>.py): the parameters a
+  token multiplies through (the active experts only, the N of MFU's 6·N),
+  the forward attention FLOPs of one query over `context` keys, and the
+  number of layers that run the grouped expert FFN. A reference without
+  them is an error, never a default.
 * `train_flops_per_token` / `forward_flops_per_token` -- model FLOPs of the
-  algorithm: matmuls and causal attention, no recomputation, no capacity
-  padding.
+  algorithm, composed from the reference's counts: matmuls and attention, no
+  recomputation, no capacity padding.
 * `expert_ffn_cost` -- FLOPs and HBM bytes of one grouped expert-FFN call on
   its (experts, capacity, d) buffer, whatever kernel implements it.
 """
@@ -17,6 +22,9 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import os
+
+COUNTED = ("matmul_params_per_token", "attention_flops_per_token", "moe_layers")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,27 +54,38 @@ def peaks_for(device_kind: str) -> Peaks:
         ) from None
 
 
+def reference(cfg: dict):
+    """The plain reference module the configuration names (`reference`, a
+    path from the repository's root)."""
+    from run import ROOT, load_module
+
+    return load_module(os.path.join(ROOT, cfg["reference"]))
+
+
+def _counted(cfg: dict, name: str):
+    fn = getattr(reference(cfg), name, None)
+    if fn is None:
+        raise AttributeError(f"the reference {cfg['reference']} has no counting function "
+                             f"{name!r}; each reference exports {COUNTED}")
+    return fn
+
+
 def matmul_params_per_token(cfg: dict) -> int:
-    """Weights one token is multiplied by: attention projections, router,
-    its top-k routed experts, shared experts, and the (tied) unembedding.
-    The embedding lookup is a gather, not a matmul."""
-    d, h, kv, hd = cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
-    f, m, k = cfg["moe_d_ff"], cfg["n_experts"], cfg["top_k"]
-    attn = d * hd * (2 * h + 2 * kv)
-    ffn = 3 * d * f * (k + cfg["n_shared_experts"]) + d * m
-    return cfg["n_layers"] * (attn + ffn) + cfg["vocab_size"] * d
+    return _counted(cfg, "matmul_params_per_token")(cfg)
 
 
 def attention_flops_per_token(cfg: dict, context: float) -> float:
-    """Forward FLOPs of scores and weighted values for one query that attends
-    `context` keys: 2 matmuls x 2 FLOPs per multiply-add x heads x head_dim."""
-    return cfg["n_layers"] * 4.0 * cfg["n_heads"] * cfg["head_dim"] * context
+    return _counted(cfg, "attention_flops_per_token")(cfg, context)
+
+
+def moe_layers(cfg: dict) -> int:
+    return _counted(cfg, "moe_layers")(cfg)
 
 
 def train_flops_per_token(cfg: dict, seq_len: int) -> float:
     """Forward + backward model FLOPs per trained token at `seq_len`: 6·N for
-    the matmuls, 3x the forward causal attention (a query at position i
-    attends i+1 keys, (S+1)/2 on average)."""
+    the matmuls, 3x the forward attention at the causal mean context (a query
+    at position i attends i+1 keys, (S+1)/2 on average)."""
     return 6.0 * matmul_params_per_token(cfg) + 3.0 * attention_flops_per_token(
         cfg, (seq_len + 1) / 2.0)
 

@@ -18,7 +18,6 @@ import time
 
 import numpy as np
 
-BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 IN_FLIGHT = 2  # steps queued ahead of the one the host waits on
 
 
@@ -51,14 +50,15 @@ class Trainer:
     def __init__(self, spec):
         import jax
 
+        import counts
         import program
-        from run import generator, load_module
+        from run import generator
 
         from repro.models import build_model
         from repro.optim import adamw as program_adamw
 
         self.spec, self.mix, self.cfg = spec, spec.traffic, spec.config
-        self.ref = load_module(os.path.join(BENCH, os.path.relpath(self.cfg["reference"], "bench")))
+        self.ref = counts.reference(self.cfg)
         mcfg = program.model_config(self.cfg, **self.mix["program"])
         self.model = build_model(mcfg)
         program.check_params(self.model, self.ref.param_shapes(self.cfg))

@@ -1,6 +1,7 @@
 """Model FLOP/s utilization of the whole train step (%): model FLOPs per
-token (6·N_active + causal attention, from the configuration's shapes; no
-recomputation, no capacity padding) x the run's tokens/s over chips x peak."""
+token (6·N_active + attention, counts.train_flops_per_token from the counts
+of the configuration's reference; no recomputation, no capacity padding) x
+the run's tokens/s over chips x peak."""
 
 import counts
 

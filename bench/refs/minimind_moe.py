@@ -92,6 +92,33 @@ def init_params(cfg: dict, key) -> dict:
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
+# ---------------------------------------------------------------- counting
+# What bench/counts.py reads of this architecture: every layer is MoE, with
+# grouped-query attention and shared experts at the routed width.
+
+
+def matmul_params_per_token(cfg: dict) -> int:
+    """Weights one token is multiplied by: attention projections, router,
+    its top-k routed experts, shared experts, and the (tied) unembedding.
+    The embedding lookup is a gather, not a matmul."""
+    d, h, kv, hd = cfg["d_model"], cfg["n_heads"], cfg["n_kv_heads"], cfg["head_dim"]
+    f, m, k = cfg["moe_d_ff"], cfg["n_experts"], cfg["top_k"]
+    attn = d * hd * (2 * h + 2 * kv)
+    ffn = 3 * d * f * (k + cfg["n_shared_experts"]) + d * m
+    return cfg["n_layers"] * (attn + ffn) + cfg["vocab_size"] * d
+
+
+def attention_flops_per_token(cfg: dict, context: float) -> float:
+    """Forward FLOPs of scores and weighted values for one query that attends
+    `context` keys: 2 matmuls x 2 FLOPs per multiply-add x heads x head_dim."""
+    return cfg["n_layers"] * 4.0 * cfg["n_heads"] * cfg["head_dim"] * context
+
+
+def moe_layers(cfg: dict) -> int:
+    """Layers that run the grouped expert FFN: all of them."""
+    return cfg["n_layers"]
+
+
 # ------------------------------------------------------------------ matmul
 
 

@@ -1,29 +1,25 @@
-"""The control against the limits.
+"""The control against the limits, for every cell that keeps readings.
 
-At a size a CPU test run can hold, the reference computed one precision
-below the configured bfloat16 (float8 e4m3) in the program's place, and the
-half-batch fault, must read well above the sound program on at least one
-compared number. At the cell's own size, the readings bench/calibrate.py
-took on the chip (fixtures/train-16e.readings.json) go through the same
-comparison and the cell's limits file as a run's do: the program's must be
-correct on every seed, the control's and the fault's not correct on any."""
-import json
-import os
-
+At a size a CPU test run can hold (fixtures/<cell>.cpu.json), the reference
+computed one precision below the configured bfloat16 (float8 e4m3) in the
+program's place, and the half-batch fault, must read well above the sound
+program on at least one compared number. At the cell's own size, the
+readings bench/calibrate.py took on the chip
+(fixtures/<cell>.readings.json) go through the same comparison and the
+cell's limits file as a run's do: the program's must be correct on every
+seed, the control's and the fault's not correct on any."""
 import pytest
 
 import calibrate
+import cells
 import run
 from kinds.train import compare
-from test_train_faults import _tiny_spec as tiny_train
-
-FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures",
-                       "train-16e.readings.json")
 
 
-def test_train_control_and_fault_separate():
+@pytest.mark.parametrize("cell", cells.cpu_cells("train"))
+def test_train_control_and_fault_separate(cell):
     run.setup_jax()
-    rows, _ = calibrate.calibrate_train(tiny_train(), [5], out=lambda s: None)
+    rows, _ = calibrate.calibrate_train(cells.cpu_spec(cell), [5], out=lambda s: None)
     got = {r["reading"]: r for r in rows}
     prog = got["program"]
     keys = ("loss_gap", "grad_norm_gap", "change_norm_gap")
@@ -33,10 +29,11 @@ def test_train_control_and_fault_separate():
 
 @pytest.mark.parametrize("reading,correct", [
     ("program", True), ("control_fp8", False), ("fault_half_batch", False)])
-def test_chip_readings_against_the_cell_limits(reading, correct):
-    with open(FIXTURE) as fh:
-        saved = json.load(fh)
-    limits = run.load_spec(saved["workload"]).limits
+@pytest.mark.parametrize("cell", cells.reading_cells())
+def test_chip_readings_against_the_cell_limits(cell, reading, correct):
+    saved = cells.readings(cell)
+    assert saved["workload"] == cell
+    limits = run.load_spec(cell).limits
     assert len(saved["readings"]) >= 12
     for seed, r in saved["readings"].items():
         nums = compare(r[reading], r["reference"])

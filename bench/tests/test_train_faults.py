@@ -1,33 +1,25 @@
-"""A whole train-16e run at a CPU-sized configuration, past the harness's
-look for a chip, with the timed path sound and then broken underneath:
-`correct` must be true for the sound program and false for each fault a
-one-chip training cell can have (a step that returns its state unchanged;
-half of the batch left out, the mean taken over the rest)."""
+"""A whole run of every training cell that keeps a CPU size
+(fixtures/<cell>.cpu.json), past the harness's look for a chip, with the
+timed path sound and then broken underneath: `correct` must be true for the
+sound program and false for each fault a one-chip training cell can have
+(a step that returns its state unchanged; half of the batch left out, the
+mean taken over the rest)."""
 import time
 
 import jax
 import pytest
 
+import cells
 import run
 from repro.training import loop
 
-
-def _tiny_spec():
-    spec = run.load_spec("train-16e")
-    spec.config = dict(spec.config, n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
-                       head_dim=16, moe_d_ff=96, vocab_size=512, n_experts=4, top_k=2,
-                       max_seq_len=256, attn_chunk=64)
-    spec.traffic = dict(spec.traffic, seq_len=256, ring=4)
-    # limits for this size, from CPU readings of the sound program (loss
-    # <= 2.2e-5, gradient <= 1.4e-3, change <= 2.2e-3) and of the faults
-    # (half batch: >= 8e-4, 0.63, 0.03; unchanged state: 1)
-    spec.limits = {"loss_gap": 1e-4, "grad_norm_gap": 5e-2, "change_norm_gap": 1e-2}
-    return spec
+CELLS = cells.cpu_cells("train")
 
 
-def _run(seed=5):
+def _run(cell, seed=5):
     run.setup_jax()
-    ctx = run.Context(_tiny_spec(), seed, 1.0, False, jax.devices(), time.perf_counter())
+    ctx = run.Context(cells.cpu_spec(cell), seed, 1.0, False, jax.devices(),
+                      time.perf_counter())
     return run.run_cell(ctx)
 
 
@@ -54,8 +46,9 @@ def _half_batch(real):
     return make
 
 
-def test_sound_run_is_correct():
-    res = _run()
+@pytest.mark.parametrize("cell", CELLS)
+def test_sound_run_is_correct(cell):
+    res = _run(cell)
     assert res["correct"], res["checks"]
     assert res["attempted"] >= 1 and res["failed"] == 0
     assert set(res["metrics"]) == {"train_tokens_per_s", "setup_s"}
@@ -63,7 +56,8 @@ def test_sound_run_is_correct():
 
 
 @pytest.mark.parametrize("fault", [_unchanged, _half_batch])
-def test_fault_is_not_correct(monkeypatch, fault):
+@pytest.mark.parametrize("cell", CELLS)
+def test_fault_is_not_correct(monkeypatch, cell, fault):
     monkeypatch.setattr(loop, "make_train_step", fault(loop.make_train_step))
-    res = _run()
+    res = _run(cell)
     assert not res["correct"], res["checks"]
