@@ -15,8 +15,15 @@ program under test, so a change to the program cannot change what is counted.
 * `train_flops_per_token` / `forward_flops_per_token` -- model FLOPs of the
   algorithm, composed from the reference's counts: matmuls and attention, no
   recomputation, no capacity padding.
+* `expert_capacity` -- rows per expert buffer, over the router's width
+  (`n_experts`, as published), as the program sizes it.
+* `experts_held` -- the experts this chip holds of each expert layer: the
+  configuration's `experts_held`, one chip's share of a layer whose experts
+  are divided evenly over several chips, or all `n_experts` where it states
+  none.
 * `expert_ffn_cost` -- FLOPs and HBM bytes of one grouped expert-FFN call on
-  its (experts, capacity, d) buffer, whatever kernel implements it.
+  its (experts, capacity, d) buffer, whatever kernel implements it; the
+  readers give it the experts held.
 """
 from __future__ import annotations
 
@@ -96,9 +103,22 @@ def forward_flops_per_token(cfg: dict, context: float) -> float:
 
 
 def expert_capacity(cfg: dict, n_tokens: int) -> int:
-    """Rows per expert buffer: ceil(k·n/m · capacity_factor)."""
+    """Rows per expert buffer: ceil(k·n/m · capacity_factor), with m the
+    router's width (`n_experts`), also where this chip holds a share."""
     return max(int(math.ceil(cfg["top_k"] * n_tokens / cfg["n_experts"]
                              * cfg["capacity_factor"])), 1)
+
+
+def experts_held(cfg: dict) -> int:
+    """Experts this chip holds of each expert layer's `n_experts`: the stated
+    `experts_held`, else all of them. The experts of a layer are divided
+    evenly over the chips that share it, so the share divides `n_experts`."""
+    m = cfg["n_experts"]
+    held = cfg.get("experts_held", m)
+    if not (isinstance(held, int) and 1 <= held <= m and m % held == 0):
+        raise ValueError(f"{cfg.get('name', 'config')}: experts_held {held!r} is not an even "
+                         f"share of the router's {m} experts")
+    return held
 
 
 def expert_ffn_cost(cfg: dict, n_experts: int, capacity: int, act_bytes: int = 2):

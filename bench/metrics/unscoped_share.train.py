@@ -1,10 +1,11 @@
 """Share of device busy time spent in ops outside every layer scope of the
 train step (%): the scan carries, remat copies and casts XLA places
 between the disjoint layer scopes `embed`, `attn`, `norm`, `router`,
-`moe`, `lm_head` and `train/apply`. Read only where the program has the
-layer map (an `attn` scope)."""
+`moe`, `lm_head`, `train/apply` and `mlp` (a dense FFN layer's, such as a
+leading dense layer's; a model without one has no op under it). Read only
+where the program has the layer map (an `attn` scope)."""
 
-SCOPES = ("embed", "attn", "norm", "router", "moe", "lm_head", "train/apply")
+SCOPES = ("embed", "attn", "norm", "router", "moe", "lm_head", "train/apply", "mlp")
 
 
 def read(rec):

@@ -7,8 +7,9 @@ and sets on it every field the file states:
 * flat keys, the published sizes that the reference and the counts read
   too: each key of `TOP` sets the field of its name where the file states
   it; `d_ff` (the dense FFN width) defaults to `moe_d_ff`; `n_experts`,
-  `top_k`, `router`, `bip_iters`, `score_fn` and `capacity_factor` set
-  `RoutingSpec` fields (`ROUTING`);
+  `top_k`, `router`, `bip_iters`, `score_fn`, `capacity_factor` and
+  `experts_held` (the experts this chip holds of the router's `n_experts`;
+  counts.experts_held) set `RoutingSpec` fields (`ROUTING`);
 * `"fields"`, an object of any further `ModelConfig` fields
   (`attn_pattern`, `window_size`, `moe_pattern`, ...), with `"routing"`
   inside it an object of `RoutingSpec` fields. JSON lists become tuples and
@@ -28,7 +29,7 @@ TOP = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff", "moe_
        "attn_chunk", "tie_embeddings", "param_dtype", "compute_dtype")
 ROUTING = {"n_experts": "n_experts", "top_k": "top_k", "router": "strategy",
            "bip_iters": "bip_iters", "score_fn": "score_fn",
-           "capacity_factor": "capacity_factor"}
+           "capacity_factor": "capacity_factor", "experts_held": "experts_held"}
 ROUTING_OPTIONS = ("use_kernel", "moe_impl")
 
 

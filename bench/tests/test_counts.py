@@ -52,6 +52,24 @@ def test_expert_ffn_cost_and_roofline():
     assert bound == "compute" and t == pytest.approx(flops / 197e12)
 
 
+def test_experts_held_defaults_to_the_routers_width():
+    for name in ("minimind-moe-16e", "minimind-moe-64e"):
+        cfg = _cfg(name)
+        assert "experts_held" not in cfg
+        assert counts.experts_held(cfg) == cfg["n_experts"]
+
+
+@pytest.mark.parametrize("held,ok", [(8, True), (64, True), (1, True), (0, False),
+                                     (65, False), (6, False)])
+def test_experts_held_is_an_even_share(held, ok):
+    cfg = dict(_cfg("minimind-moe-64e"), experts_held=held)
+    if ok:
+        assert counts.experts_held(cfg) == held
+    else:
+        with pytest.raises(ValueError, match="not an even share of the router's 64"):
+            counts.experts_held(cfg)
+
+
 def test_unknown_device_kind_is_an_error():
     with pytest.raises(KeyError):
         counts.peaks_for("cpu")

@@ -43,9 +43,15 @@ def test_unscoped_share_on_the_toy_trace():
 
 def test_unscoped_share_sums_every_layer_scope():
     unscoped = _reader("unscoped_share.train")
-    shares = dict(zip(unscoped.SCOPES, (0.01, 0.20, 0.04, 0.15, 0.45, 0.03, 0.02)))
+    shares = {"embed": 0.01, "attn": 0.20, "norm": 0.04, "router": 0.15, "moe": 0.40,
+              "lm_head": 0.03, "train/apply": 0.02, "mlp": 0.05}
+    assert sorted(unscoped.SCOPES) == sorted(shares)
     got = unscoped.read(_rec({s: 2 * v for s, v in shares.items()}, busy_s=1.0, n_devices=2))
     assert got == pytest.approx(10.0)
+    # a dense FFN's time under `mlp` is a layer's, not unscoped
+    del shares["mlp"]
+    got = unscoped.read(_rec({s: 2 * v for s, v in shares.items()}, busy_s=1.0, n_devices=2))
+    assert got == pytest.approx(15.0)
 
 
 def test_attention_share():
@@ -76,6 +82,29 @@ def test_expert_ffn_bwd_roofline_at_train_16e():
     rec["trace"]["scope_s"].update({"moe/gemm/bwd/dgrad": 0.0, "moe/gemm/bwd/wgrad": 0.0})
     assert bwd.read(rec) is None
     assert bwd.read({}) is None
+
+
+@pytest.mark.parametrize("name", ["expert_ffn_roofline.train",
+                                  "expert_ffn_bwd_roofline.train"])
+def test_expert_ffn_readers_count_the_experts_held(name):
+    """One chip's share: 8 of a 64-wide router's experts, top-6, at 4096
+    tokens a microbatch. Capacity is over the router's width,
+    ceil(6 · 4096 / 64 · 1.25) = 480 rows; the grouped work is over the 8
+    experts held, not the 64 scored."""
+    reader = _reader(name)
+    cfg = dict(_json("configs", "minimind-moe-64e.json"), name="share", d_model=2048,
+               n_layers=4, n_experts=64, experts_held=8, top_k=6)
+    mix = dict(_json("traffic", "train_8k.json"), batch=4, seq_len=4096, microbatches=4)
+    rec = _rec({s: 0.5 for s in ("moe/gemm", "moe/gemm/bwd/dgrad", "moe/gemm/bwd/wgrad")})
+    rec.update(config=cfg, traffic=mix, device_kind="TPU v5 lite", steps_traced=3)
+    flops = 2.0 * 8 * 480 * 2048 * 1408 * 3
+    assert flops / 197e12 > 2 * (3 * 8 * 2048 * 1408 + 2 * 8 * 480 * 2048) / 819e9
+    calls = 4 * 4 * 3  # MoE layers x microbatches x traced steps
+    if name == "expert_ffn_roofline.train":
+        want = flops / 197e12 * calls * 4 / 0.5  # forward, remat forward, backward at 2x
+    else:
+        want = 2 * flops / 197e12 * calls / 1.0  # dgrad + wgrad
+    assert reader.read(rec) == pytest.approx(100 * want)
 
 
 def test_step_build_s_sums_the_train_step_build_spans(monkeypatch):

@@ -1,7 +1,9 @@
 """How a configuration file becomes the ModelConfig that runs
 (bench/program.py): the minimind files build what they built before the file
-could state fields, stated fields build as stated, and a name that is no
-field, or a built value that differs from a stated one, is an error."""
+could state fields (every field the fixture lists at its value, every other
+field at its declared default), stated fields build as stated, and a name
+that is no field, or a built value that differs from a stated one, is an
+error."""
 import dataclasses
 import json
 import os
@@ -23,7 +25,14 @@ def _json(*parts):
         return json.load(fh)
 
 
+def _before(name, mix="as_stated"):
+    with open(BEFORE) as fh:
+        return json.load(fh)[name][mix]
+
+
 def _jsonable(v):
+    if dataclasses.is_dataclass(v):
+        return _jsonable(dataclasses.asdict(v))
     if isinstance(v, dict):
         return {k: _jsonable(x) for k, x in v.items()}
     if isinstance(v, (list, tuple)):
@@ -33,13 +42,93 @@ def _jsonable(v):
     return np.dtype(v).name
 
 
+def _default(field):
+    if field.default is not dataclasses.MISSING:
+        return field.default
+    if field.default_factory is not dataclasses.MISSING:
+        return field.default_factory()
+    return dataclasses.MISSING
+
+
+def departures(built, before: dict, where: str) -> list:
+    """Where `built` departs from `before`, the fixture's record of an earlier
+    build: a field it lists at another value (nested configs field by field),
+    a field it lists that the build no longer has, or a field it does not list
+    at other than the default its dataclass declares. A field added with an
+    inert default leaves the build as it was and passes."""
+    fields = {f.name: f for f in dataclasses.fields(built)}
+    out = [f"{where}.{k}: in the fixture, not a field of the build"
+           for k in before if k not in fields]
+    for k, field in fields.items():
+        got = getattr(built, k)
+        if k not in before:
+            default = _default(field)
+            if default is dataclasses.MISSING or _jsonable(got) != _jsonable(default):
+                out.append(f"{where}.{k}: not in the fixture, built {got!r}, "
+                           f"declared default {default!r}")
+        elif dataclasses.is_dataclass(got) and isinstance(before[k], dict):
+            out += departures(got, before[k], f"{where}.{k}")
+        elif _jsonable(got) != before[k]:
+            out.append(f"{where}.{k}: built {_jsonable(got)!r}, before {before[k]!r}")
+    return out
+
+
 @pytest.mark.parametrize("mix", ["as_stated", "train_8k"])
 @pytest.mark.parametrize("name", ["minimind-moe-16e", "minimind-moe-64e"])
 def test_minimind_builds_as_before(name, mix):
     options = {} if mix == "as_stated" else _json("traffic", mix + ".json")["program"]
     got = program.model_config(_json("configs", name + ".json"), **options)
-    assert _jsonable(dataclasses.asdict(got)) == _json("tests", "fixtures",
-                                                      "model_configs.json")[name][mix]
+    assert departures(got, _before(name, mix), name) == []
+
+
+@dataclasses.dataclass(frozen=True)
+class _Routing(configs.RoutingSpec):
+    held_share: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class _WithRank(configs.ModelConfig):
+    latent_rank: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class _WithRouting(configs.ModelConfig):
+    routing: configs.RoutingSpec = _Routing()
+
+
+def _as(cls, built, **values):
+    return cls(**{f.name: getattr(built, f.name) for f in dataclasses.fields(built)}, **values)
+
+
+@pytest.mark.parametrize("added", ["latent_rank", "routing.held_share"])
+def test_the_comparator_takes_a_new_field_only_at_its_default(added):
+    before = _before("minimind-moe-16e")
+    built = program.model_config(_json("configs", "minimind-moe-16e.json"))
+    if added == "latent_rank":
+        at_default, changed = _as(_WithRank, built), _as(_WithRank, built, latent_rank=512)
+    else:
+        routing = _as(_Routing, built.routing)
+        at_default = dataclasses.replace(_as(_WithRouting, built), routing=routing)
+        changed = dataclasses.replace(at_default, routing=dataclasses.replace(
+            routing, held_share=8))
+    assert departures(at_default, before, "m") == []
+    assert [d.split(":")[0] for d in departures(changed, before, "m")] == ["m." + added]
+
+
+def test_the_comparator_refuses_a_changed_or_removed_field():
+    before = _before("minimind-moe-16e")
+    built = program.model_config(_json("configs", "minimind-moe-16e.json"))
+    changed = dataclasses.replace(built, routing=dataclasses.replace(built.routing, top_k=2))
+    assert [d.split(":")[0] for d in departures(changed, before, "m")] == ["m.routing.top_k"]
+    assert departures(dataclasses.replace(built, window_size=1024), before, "m")
+    removed = dict(before, attn_window=1024)
+    assert [d.split(":")[0] for d in departures(built, removed, "m")] == ["m.attn_window"]
+
+
+def test_experts_held_is_a_routing_field():
+    cfg = {"name": "share", "n_experts": 64, "top_k": 6, "experts_held": 8}
+    assert program.stated_fields(cfg) == {
+        "routing": {"n_experts": 64, "top_k": 6, "experts_held": 8}}
 
 
 def _windowed():
